@@ -84,7 +84,8 @@ def bench_row(batch: int, size: int, n_dirs: int, repeats: int) -> dict:
     t0 = time.perf_counter()
     sw_p, bn_p = [np.asarray(a) for a in
                   dist_ops.pairwise_distances(pts, diag, prof,
-                                              use_pallas=True)]
+                                              use_pallas=True,
+                                              interpret=True)]
     pallas_s = time.perf_counter() - t0
     bit_identical = (np.array_equal(sw_x, sw_p)
                      and np.array_equal(bn_x, bn_p))

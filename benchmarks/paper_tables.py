@@ -71,7 +71,8 @@ def plan_cache_summary() -> dict:
              "hits": 0, "misses": 0, "regrows": 0}
     for eng in ENGINES.values():
         for k, v in eng.plan_stats().items():
-            total[k] += v
+            if k in total:
+                total[k] += v
     return total
 
 

@@ -26,11 +26,11 @@ consumed ranks, so the two paths are bit-identical
 The packed path needs 64-bit integers, which JAX disables by default.
 Rather than flipping ``jax_enable_x64`` globally (which would change
 default dtypes across the whole process), every public entry point wraps
-its **outermost** jit call in :func:`key_scope` — the scope must cover
-trace *and* lowering, which is why it cannot live inside a jitted
-function.  :func:`resolve_merge_keys` falls back to ``"rank"`` whenever
-packing cannot be used: > 32-bit dtypes, a missing x64 context manager,
-or a caller tracing us inside their own jit without the scope active
+its **outermost** jit call in :func:`key_scope` (``jax.enable_x64``) —
+the scope must cover trace *and* lowering, which is why it cannot live
+inside a jitted function.  :func:`resolve_merge_keys` falls back to
+``"rank"`` whenever packing cannot be used: > 32-bit dtypes, or a caller
+tracing us inside their own jit without the scope active
 (results are bit-identical either way; only performance differs).
 
 NaNs are outside the contract: a stable argsort orders every NaN after
@@ -44,11 +44,6 @@ import contextlib
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-try:  # jax >= 0.4: experimental but present; absence just disables packing
-    from jax.experimental import enable_x64 as _enable_x64
-except ImportError:  # pragma: no cover - exercised only on exotic installs
-    _enable_x64 = None
 
 MERGE_KEYS = ("packed", "rank")
 FILTRATIONS = ("superlevel", "sublevel")
@@ -149,28 +144,28 @@ def packable_dtype(dtype) -> bool:
     return False
 
 
-def x64_available() -> bool:
-    """True when int64 keys can be materialized (scope or global flag)."""
-    return _enable_x64 is not None or bool(jax.config.jax_enable_x64)
+def _top_level() -> bool:
+    """True when no trace is in progress (eager, outermost call)."""
+    return jax.core.trace_ctx.is_top_level()
 
 
 def resolve_merge_keys(requested: str, dtype) -> str:
     """Resolve a ``merge_keys`` request against what can actually run.
 
     ``"packed"`` degrades to ``"rank"`` (bit-identical, just argsort-keyed)
-    when the dtype exceeds 32 bits, when no x64 scope can be opened, or
-    when we are already inside someone else's trace without x64 active —
-    entering the scope mid-trace would not cover lowering, and tracing
-    int64 ops without it silently truncates them.
+    when the dtype exceeds 32 bits, or when we are already inside someone
+    else's trace without x64 active — entering the scope mid-trace would
+    not cover lowering, and tracing int64 ops without it silently
+    truncates them.
     """
     if requested not in MERGE_KEYS:
         raise ValueError(f"merge_keys must be one of {MERGE_KEYS}, "
                          f"got {requested!r}")
     if requested == "rank":
         return "rank"
-    if not packable_dtype(dtype) or not x64_available():
+    if not packable_dtype(dtype):
         return "rank"
-    if not jax.core.trace_state_clean() and not jax.config.jax_enable_x64:
+    if not _top_level() and not jax.config.jax_enable_x64:
         return "rank"
     return "packed"
 
@@ -182,10 +177,9 @@ def key_scope(merge_keys: str):
     already in progress (the outer caller holds the scope then — entering
     here could not cover lowering anyway).
     """
-    if (merge_keys == "packed" and _enable_x64 is not None
-            and not jax.config.jax_enable_x64
-            and jax.core.trace_state_clean()):
-        return _enable_x64()
+    if (merge_keys == "packed" and not jax.config.jax_enable_x64
+            and _top_level()):
+        return jax.enable_x64(True)
     return contextlib.nullcontext()
 
 

@@ -227,9 +227,10 @@ def resolve_labels_frontier(pointers: jnp.ndarray, shape: tuple[int, int],
     row_slot_np = np.full(h, -1, np.int32)
     row_slot_np[b_rows] = np.arange(len(b_rows), dtype=np.int32)
     row_slot = jnp.asarray(row_slot_np)
-    b_flat = jnp.asarray(
-        (b_rows[:, None].astype(np.int64) * w
-         + np.arange(w, dtype=np.int64)[None, :]).reshape(-1).astype(np.int32))
+    # Built from the (h / strip_rows)-entry row list on the device: as a
+    # host constant the O(n / strip_rows) table bloats every compile.
+    b_flat = (jnp.asarray(b_rows)[:, None] * jnp.int32(w)
+              + jnp.arange(w, dtype=jnp.int32)[None, :]).reshape(-1)
 
     def follow(table, q):
         rs = row_slot[q // w]

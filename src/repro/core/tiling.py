@@ -812,13 +812,23 @@ def _tiled_pixhomology_stacks(pvals: jnp.ndarray, pgidx: jnp.ndarray,
                     sp(2), sp(2), sp(2), sp(2), sp(2),
                     sp(1), sp(1), sp(1), sp(0), sp(0), sp(0), sp(0)))
 
-    state = phase_ab(pvals, pgidx, tv)
-    td = merge_tile_state(
-        state, tv, shape=(h, w), grid=grid, max_features=max_features,
-        tile_max_features=tile_max_features,
+    merge = functools.partial(
+        merge_tile_state, shape=(h, w), grid=grid,
+        max_features=max_features, tile_max_features=tile_max_features,
         tile_max_candidates=tile_max_candidates, truncated=truncated,
         merge_keys=merge_keys, phase_c_impl=phase_c_impl,
         phase_c_block=phase_c_block)
+    if shard_ctx is not None and shard_ctx.mesh.size > 1:
+        from jax.sharding import PartitionSpec as P
+
+        from repro.distributed.context import shard_map_compat
+
+        # The O(boundary) seam merge runs whole on every device.  Left to
+        # XLA's partitioner it would fail on TPU: a Mosaic kernel (the
+        # phase-C reduction) cannot be partitioned automatically.
+        merge = shard_map_compat(merge, mesh=shard_ctx.mesh,
+                                 in_specs=(P(), P()), out_specs=P())
+    td = merge(phase_ab(pvals, pgidx, tv), tv)
     if filtration == "sublevel":
         d = td.diagram
         td = td._replace(diagram=d._replace(birth=jnp.negative(d.birth),

@@ -6,25 +6,10 @@ import dataclasses
 import jax
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.6 exports shard_map at top level
-    _shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# The replication-check kwarg was renamed check_rep -> check_vma; probe the
-# signature once so genuine caller TypeErrors are never masked by a retry.
-try:
-    import inspect
-    _CHECK_KW = ("check_vma" if "check_vma" in
-                 inspect.signature(_shard_map).parameters else "check_rep")
-except (ValueError, TypeError):  # pragma: no cover - unintrospectable
-    _CHECK_KW = "check_rep"
-
-
 def shard_map_compat(fn, **kwargs):
-    """``shard_map`` with replication checking off, across jax versions."""
-    kwargs.setdefault(_CHECK_KW, False)
-    return _shard_map(fn, **kwargs)
+    """``jax.shard_map`` with replication checking off."""
+    kwargs.setdefault("check_vma", False)
+    return jax.shard_map(fn, **kwargs)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.backend import I32_ZERO
 from repro.kernels.maxpool.ref import _neg_inf, _pos_inf
 
 _LANES = 128
@@ -106,8 +107,8 @@ def _pool_call(x: jnp.ndarray, *, want_arg: bool, minimum: bool,
 
     kernel = functools.partial(_maxarg_kernel, width=w, block_rows=th,
                                want_arg=want_arg, minimum=minimum)
-    in_spec = pl.BlockSpec((th, w + 2), lambda i: (i, 0))
-    out_spec = pl.BlockSpec((th, w), lambda i: (i, 0))
+    in_spec = pl.BlockSpec((th, w + 2), lambda i: (i, I32_ZERO))
+    out_spec = pl.BlockSpec((th, w), lambda i: (i, I32_ZERO))
     out_val, out_arg = pl.pallas_call(
         kernel,
         grid=(hp // th,),

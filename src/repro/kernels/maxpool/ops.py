@@ -1,8 +1,9 @@
 """Jit'd public wrappers for the 3x3 pooling ops with backend dispatch.
 
-`use_pallas=None` (default) auto-selects: the Pallas TPU kernel on TPU
-backends, the pure-jnp reference elsewhere (this container is CPU-only, so CI
-exercises the kernel via interpret mode in tests).
+``use_pallas``/``interpret`` resolve through
+:func:`repro.kernels.backend.resolve`: the compiled Pallas kernel on TPU,
+the pure-jnp reference elsewhere, the Pallas interpreter only on
+``interpret=True``.
 """
 from __future__ import annotations
 
@@ -11,11 +12,14 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import backend
 from repro.kernels.maxpool import ref
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _pallas(use_pallas, interpret):
+    """``None`` for the reference, else the kernel's ``interpret`` flag."""
+    impl = backend.resolve("maxpool", use_pallas, interpret)
+    return None if impl == backend.XLA else impl == backend.INTERPRET
 
 
 @functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
@@ -25,31 +29,28 @@ def maxargmaxpool3x3(x: jnp.ndarray, *, use_pallas: bool | None = None,
 
     Returns (max: x.dtype, argmax: int32 flat index), shapes == x.shape.
     """
-    if use_pallas is None:
-        use_pallas = _on_tpu()
-    if use_pallas:
+    interp = _pallas(use_pallas, interpret)
+    if interp is not None:
         from repro.kernels.maxpool import kernel
-        return kernel.maxargmaxpool3x3(x, interpret=interpret or not _on_tpu())
+        return kernel.maxargmaxpool3x3(x, interpret=interp)
     return ref.maxargmaxpool3x3(x)
 
 
 @functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
 def maxpool3x3(x: jnp.ndarray, *, use_pallas: bool | None = None,
                interpret: bool = False):
-    if use_pallas is None:
-        use_pallas = _on_tpu()
-    if use_pallas:
+    interp = _pallas(use_pallas, interpret)
+    if interp is not None:
         from repro.kernels.maxpool import kernel
-        return kernel.maxpool3x3(x, interpret=interpret or not _on_tpu())
+        return kernel.maxpool3x3(x, interpret=interp)
     return ref.maxpool3x3(x)
 
 
 @functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
 def minpool3x3(x: jnp.ndarray, *, use_pallas: bool | None = None,
                interpret: bool = False):
-    if use_pallas is None:
-        use_pallas = _on_tpu()
-    if use_pallas:
+    interp = _pallas(use_pallas, interpret)
+    if interp is not None:
         from repro.kernels.maxpool import kernel
-        return kernel.minpool3x3(x, interpret=interpret or not _on_tpu())
+        return kernel.minpool3x3(x, interpret=interp)
     return ref.minpool3x3(x)

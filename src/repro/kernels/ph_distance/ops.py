@@ -4,8 +4,10 @@ Two public entry points:
 
 * :func:`pairwise_distances` — the (B, B) matrix pair reduction over
   pre-built projection/profile tables, routed to the Pallas kernel
-  (TPU, or ``interpret=True`` anywhere) or the bit-identical XLA
-  reference.
+  (``interpret=True`` anywhere) or the bit-identical XLA reference.  The
+  kernel sorts inside its body, which Mosaic cannot lower, so on TPU
+  the XLA reference is the implementation
+  (:data:`repro.kernels.backend.NO_MOSAIC`).
 
 * :func:`diagram_distances` — the whole-batch driver: capacity-padded
   diagram arrays in, ``(sw, bn)`` matrices out.  The preparation stages
@@ -23,32 +25,22 @@ through); ``PHEngine.distance_matrix`` re-checks its host inputs.
 """
 from __future__ import annotations
 
-import jax
-
 from repro.core.packed_keys import check_finite
+from repro.kernels import backend
 from repro.kernels.ph_distance import kernel, ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def pairwise_distances(pts, diag, prof, *, use_pallas: bool | None = None,
                        interpret: bool = False):
-    """Pair-grid ``(sw, bn)`` matrices, Pallas or XLA backend.
-
-    ``use_pallas=None`` auto-selects: the Pallas kernel on TPU, the XLA
-    reference elsewhere (on CPU the vmapped reference compiles to the
-    same sorts without the pair-grid bookkeeping).  Forcing
-    ``use_pallas=True`` off-TPU runs the kernel in interpret mode (CI's
-    parity path).
+    """Pair-grid ``(sw, bn)`` matrices, Pallas or XLA backend, chosen by
+    :func:`repro.kernels.backend.resolve` (the XLA reference on every
+    compiled backend; ``interpret=True`` runs the kernel in the Pallas
+    interpreter, CI's parity path).
     """
-    if use_pallas is None:
-        use_pallas = _on_tpu()
-    if not use_pallas:
+    impl = backend.resolve("ph_distance", use_pallas, interpret)
+    if impl == backend.XLA:
         return ref.distance_matrix(pts, diag, prof)
-    return kernel.distance_matrix(pts, diag, prof,
-                                  interpret=interpret or not _on_tpu())
+    return kernel.distance_matrix(pts, diag, prof, interpret=True)
 
 
 def diagram_distances(birth, death, p_birth, *, n_dirs: int = 16,

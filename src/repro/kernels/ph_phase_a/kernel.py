@@ -1,8 +1,7 @@
 """Pallas TPU kernel for the fused PixHomology phase A.
 
-One VMEM pass per ``strip_rows``-row strip replaces what the pooled stage
-pipeline spends three HBM round trips plus the first ~log2(strip area)
-whole-image doubling iterations on (src/repro/ph/DESIGN.md §2/§Perf):
+One VMEM pass per 8-row block computes both per-pixel artifacts of
+phase A from the same resident planes (src/repro/ph/DESIGN.md §2):
 
   1. load three row-shifted planes of the (-inf)-padded image (the same
      halo trick as the maxpool kernel: BlockSpecs cannot express
@@ -12,29 +11,26 @@ whole-image doubling iterations on (src/repro/ph/DESIGN.md §2/§Perf):
      (value, row, col) total-order tie-breaking, masking out-of-image
      lanes exactly (ref.py's fill index -1 can never win — unlike the
      maxpool kernel this holds even for images containing the fill value);
-  3. pointer-chase **inside the strip**: doubling on the strip-local
-     pointer array, entirely in VMEM, until every pixel is snapped to its
-     furthest in-strip ancestor (escape targets frozen), then one
-     half-hop so emitted pointers land on basin roots or boundary rows of
-     adjacent strips — the invariant phase B's compacted frontier needs;
-  4. emit the strictly-higher 8-neighbor bitmask (basin-candidate flags)
-     from the planes already resident in VMEM.
+  3. emit the strictly-higher 8-neighbor bitmask (basin-candidate flags).
 
-VMEM working set: 3 value planes of (strip_rows, W+2) plus ~6 int32
-(strip_rows, W) temporaries — ~56 KB per strip at strip_rows=8, W=1024,
-f32; W up to ~32k columns fits 16 MB VMEM.  The in-kernel chase is a
-1D gather over the strip-local flat array; rows are padded to a multiple
-of ``strip_rows`` with -inf (pad pixels self-root, so the chase cannot
-escape into them, and the host wrapper slices them off).
+Everything in the body is elementwise over row-shifted planes, which is
+what Mosaic lowers.  The in-strip snap (pointer doubling until every
+pixel reaches its furthest in-strip ancestor, then one half-hop) is a
+data-dependent 1D gather, which Mosaic refuses ("Only 2D gather is
+supported"), so it runs after the kernel as XLA doubling — literally
+``ref.strip_snap``, the code the reference runs — and the kernel path is
+bit-identical to ``ref.phase_a`` by construction of the shared snap plus
+the per-pixel parity of the sweep (tests/test_kernels_phase_a.py).
 
-Caveat (CPU-only CI): tests pin this kernel down bit-exactly in
-*interpret* mode; the Mosaic lowering of the data-dependent
-``while_loop`` + dynamic 1D gather is not exercised here (no TPU in the
-container).  If a given jaxlib's Mosaic rejects it, the stage graph
-degrades cleanly: ``use_pallas=False`` keeps the fused stage semantics
-on the bit-identical XLA twin (``ref.phase_a``), and
-``phase_a_impl="pooled"`` is the unfused fallback — both produce
-identical diagrams (DESIGN.md §2).
+Dtypes narrower than 32 bits are widened to 32 bits before the kernel:
+the widening is exact and order-preserving, so pointers and mask bits
+are unchanged, and every plane then tiles (8, 128) like float32.  The
+kernel compiles for TPU v5e at 4096 x 4096 (tests/test_tpu_compile.py).
+
+VMEM working set: 3 value planes of (8, W+2) plus ~4 int32 (8, W)
+temporaries — ~56 KB per block at W=1024; W up to ~32k columns fits
+16 MB VMEM.  Rows are padded to a multiple of 8 with -inf; the wrapper
+slices them off.
 """
 from __future__ import annotations
 
@@ -44,19 +40,23 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.grid import NEIGHBOR_OFFSETS, fixed_point_iterate
+from repro.core.grid import NEIGHBOR_OFFSETS
+from repro.kernels.backend import I32_ZERO
 from repro.kernels.maxpool.kernel import _pad_rows, _row_shifted_planes
 from repro.kernels.maxpool.ref import _neg_inf
+from repro.kernels.ph_phase_a.ref import strip_snap
+# Rows per grid step: one sublane tile, as the (8, 128) block rule asks.
+_BLOCK_ROWS = 8
 
 
-def _phase_a_kernel(r0_ref, r1_ref, r2_ref, ptr_ref, mask_ref, *,
-                    height: int, width: int, strip_rows: int):
+def _phase_a_kernel(r0_ref, r1_ref, r2_ref, hop_ref, mask_ref, *,
+                    height: int, width: int):
     i = pl.program_id(0)
-    s, w = strip_rows, width
+    s, w = _BLOCK_ROWS, width
     planes = (r0_ref[...], r1_ref[...], r2_ref[...])   # (S, W+2) each
     x = planes[1][:, 1:1 + w]                          # self values
 
-    lr = jax.lax.broadcasted_iota(jnp.int32, (s, w), 0)  # row within strip
+    lr = jax.lax.broadcasted_iota(jnp.int32, (s, w), 0)  # row within block
     cc = jax.lax.broadcasted_iota(jnp.int32, (s, w), 1)  # column
     grow = i * jnp.int32(s) + lr                         # global row
 
@@ -78,22 +78,8 @@ def _phase_a_kernel(r0_ref, r1_ref, r2_ref, ptr_ref, mask_ref, *,
             best_v = jnp.where(take, v, best_v)
             best_dr = jnp.where(take, jnp.int32(dr), best_dr)
             best_dc = jnp.where(take, jnp.int32(dc), best_dc)
-
-    # --- in-strip snap: doubling on the strip-local pointer array ---
-    tr = lr + best_dr - 1                 # target row within strip
-    tc = cc + best_dc - 1                 # target column (in-image by mask)
-    esc = (tr < 0) | (tr >= s)            # hop leaves the strip
-    lid = lr * w + cc
-    m0 = jnp.where(esc, lid, tr * w + tc).reshape(-1)
-    m, _ = fixed_point_iterate(lambda q: q[q], m0)
-
-    # Half-hop: emitted pointers are roots or boundary-row pixels of the
-    # adjacent strips, in global flat coordinates.
-    tgt_g = ((grow + best_dr - 1) * jnp.int32(w) + tc).reshape(-1)
-    gid = (grow * jnp.int32(w) + cc).reshape(-1)
-    escf = esc.reshape(-1)
-    ptr = jnp.where(escf[m], tgt_g[m], gid[m])
-    ptr_ref[...] = ptr.reshape(s, w)
+    # Unsnapped steepest-ascent pointer in global flat coordinates.
+    hop_ref[...] = (grow + best_dr - 1) * jnp.int32(w) + (cc + best_dc - 1)
 
     # --- strictly-higher 8-neighbor bitmask (basin-candidate flags) ---
     mask = jnp.zeros((s, w), jnp.int32)
@@ -109,23 +95,33 @@ def _phase_a_kernel(r0_ref, r1_ref, r2_ref, ptr_ref, mask_ref, *,
     mask_ref[...] = mask
 
 
-@functools.partial(jax.jit, static_argnames=("strip_rows", "interpret"))
-def phase_a(image: jnp.ndarray, *, strip_rows: int = 8,
-            interpret: bool = False):
-    """Fused phase A; bit-identical to ``ref.phase_a`` (flat int32 pair)."""
+def _widen(image: jnp.ndarray) -> jnp.ndarray:
+    """Exact, order-preserving widening of < 32-bit dtypes to 32 bits."""
+    dt = jnp.dtype(image.dtype)
+    if dt.itemsize >= 4:
+        return image
+    return image.astype(jnp.float32 if dt.kind == "f" or dt == jnp.bfloat16
+                        else jnp.int32)
+
+
+def sweep(image: jnp.ndarray, *, interpret: bool = False):
+    """The kernel alone: unsnapped pointers and mask, flat int32 — the
+    Pallas twin of ``ref.pointer_and_mask_sweep``.  Every output pixel is
+    independent of the blocking, so blocks are always ``_BLOCK_ROWS``
+    rows (the sublane tile), whatever ``strip_rows`` the snap uses."""
+    image = _widen(image)
     h, w = image.shape
-    s = max(1, min(strip_rows, h))
-    hp = -(-h // s) * s                    # ceil to a strip multiple
+    s = _BLOCK_ROWS
+    hp = -(-h // s) * s                    # ceil to a block multiple
     fill = _neg_inf(image.dtype)
 
     r0, r1, r2 = _row_shifted_planes(image, fill)
     r0, r1, r2 = (_pad_rows(p, hp - h, fill) for p in (r0, r1, r2))
 
-    kernel = functools.partial(_phase_a_kernel, height=h, width=w,
-                               strip_rows=s)
-    in_spec = pl.BlockSpec((s, w + 2), lambda i: (i, 0))
-    out_spec = pl.BlockSpec((s, w), lambda i: (i, 0))
-    ptr, mask = pl.pallas_call(
+    kernel = functools.partial(_phase_a_kernel, height=h, width=w)
+    in_spec = pl.BlockSpec((s, w + 2), lambda i: (i, I32_ZERO))
+    out_spec = pl.BlockSpec((s, w), lambda i: (i, I32_ZERO))
+    hop, mask = pl.pallas_call(
         kernel,
         grid=(hp // s,),
         in_specs=[in_spec, in_spec, in_spec],
@@ -134,4 +130,12 @@ def phase_a(image: jnp.ndarray, *, strip_rows: int = 8,
                    jax.ShapeDtypeStruct((hp, w), jnp.int32)],
         interpret=interpret,
     )(r0, r1, r2)
-    return ptr[:h].reshape(-1), mask[:h].reshape(-1)
+    return hop[:h].reshape(-1), mask[:h].reshape(-1)
+
+
+@functools.partial(jax.jit, static_argnames=("strip_rows", "interpret"))
+def phase_a(image: jnp.ndarray, *, strip_rows: int = 8,
+            interpret: bool = False):
+    """Fused phase A; bit-identical to ``ref.phase_a`` (flat int32 pair)."""
+    hop, mask = sweep(image, interpret=interpret)
+    return strip_snap(hop, image.shape, strip_rows), mask

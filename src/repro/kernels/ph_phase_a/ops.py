@@ -1,9 +1,9 @@
 """Jit'd public wrapper for the fused phase-A stage with backend dispatch.
 
-``use_pallas=None`` (default) auto-selects: the Pallas TPU kernel on TPU
-backends, the pure-XLA reference elsewhere (this container is CPU-only, so
-CI exercises the kernel via interpret mode in tests — the phase-A
-interpret smoke in tier-1).
+``use_pallas``/``interpret`` resolve through
+:func:`repro.kernels.backend.resolve`: the compiled Pallas kernel on TPU,
+the pure-XLA reference elsewhere, and the Pallas interpreter only when a
+caller passes ``interpret=True`` (the tier-1 parity tests do).
 """
 from __future__ import annotations
 
@@ -13,9 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+from repro.kernels import backend
 
 
 def boundary_rows(h: int, strip_rows: int) -> np.ndarray:
@@ -45,11 +43,10 @@ def fused_phase_a(image: jnp.ndarray, *, strip_rows: int = 8,
     strictly-higher 8-neighbor bitmask in ``NEIGHBOR_OFFSETS`` bit order.
     Both backends are bit-identical (tests/test_kernels_phase_a.py).
     """
-    if use_pallas is None:
-        use_pallas = _on_tpu()
-    if use_pallas:
+    impl = backend.resolve("ph_phase_a", use_pallas, interpret)
+    if impl != backend.XLA:
         from repro.kernels.ph_phase_a import kernel
         return kernel.phase_a(image, strip_rows=strip_rows,
-                              interpret=interpret or not _on_tpu())
+                              interpret=impl == backend.INTERPRET)
     from repro.kernels.ph_phase_a import ref
     return ref.phase_a(image, strip_rows=strip_rows)

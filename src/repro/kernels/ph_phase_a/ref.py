@@ -73,32 +73,42 @@ def pointer_and_mask_sweep(image: jnp.ndarray
     return best_i, mask
 
 
-@functools.partial(jax.jit, static_argnames=("strip_rows", "with_stats"))
-def phase_a(image: jnp.ndarray, *, strip_rows: int = 8,
-            with_stats: bool = False):
-    """Fused phase A on the whole image: ``(ptr, hi_mask)`` flat int32.
+def strip_snap(hop: jnp.ndarray, shape: tuple[int, int], strip_rows: int,
+               *, with_stats: bool = False):
+    """Snap flat steepest-ascent pointers ``hop`` to each pixel's furthest
+    in-strip ancestor, plus one half-hop out of the strip.
 
-    Semantics identical to the Pallas kernel: steepest-ascent pointers
-    under the (value, flat index) total order, snapped to each pixel's
-    furthest in-strip ancestor, plus one half-hop out of the strip; and
-    the strictly-higher neighbor bitmask.  ``with_stats`` additionally
-    returns the in-strip snap iteration count (benchmarks only).
+    Pointer doubling with escapes frozen; shared verbatim by this
+    reference and the Pallas path (whose kernel emits ``hop``), so the
+    two cannot diverge here.  ``with_stats`` also returns the doubling
+    iteration count.
     """
-    h, w = image.shape
-    n = h * w
+    h, w = shape
     srows = max(1, min(strip_rows, h))
     span = w * srows                 # strip id of flat pixel g = g // span
-
-    hop2d, mask2d = pointer_and_mask_sweep(image)      # one fused sweep
-    hop = hop2d.reshape(-1)
-    hi_mask = mask2d.reshape(-1)
-
-    idx = jnp.arange(n, dtype=jnp.int32)
+    idx = jnp.arange(h * w, dtype=jnp.int32)
     esc = hop // span != idx // span                   # hop leaves the strip
     m0 = jnp.where(esc, idx, hop)                      # freeze escapes
     m, snap_iters = fixed_point_iterate(lambda q: q[q], m0)
     hm = hop[m]                                        # half-hop out
     ptr = jnp.where(hm // span != m // span, hm, m)
+    return (ptr, snap_iters) if with_stats else ptr
+
+
+@functools.partial(jax.jit, static_argnames=("strip_rows", "with_stats"))
+def phase_a(image: jnp.ndarray, *, strip_rows: int = 8,
+            with_stats: bool = False):
+    """Fused phase A on the whole image: ``(ptr, hi_mask)`` flat int32.
+
+    Semantics identical to the Pallas path: steepest-ascent pointers
+    under the (value, flat index) total order, snapped to each pixel's
+    furthest in-strip ancestor, plus one half-hop out of the strip; and
+    the strictly-higher neighbor bitmask.  ``with_stats`` additionally
+    returns the in-strip snap iteration count (benchmarks only).
+    """
+    hop2d, mask2d = pointer_and_mask_sweep(image)      # one fused sweep
+    out = strip_snap(hop2d.reshape(-1), image.shape, strip_rows,
+                     with_stats=with_stats)
     if with_stats:
-        return ptr, hi_mask, snap_iters
-    return ptr, hi_mask
+        return out[0], mask2d.reshape(-1), out[1]
+    return out, mask2d.reshape(-1)

@@ -46,36 +46,29 @@ from __future__ import annotations
 
 import functools
 
-import jax
 import jax.numpy as jnp
 
 from repro.core.grid import higher_neighbor_basins
 from repro.core.packed_keys import key_pad
 from repro.core.parallel_merge import boruvka_forest, chain_clique_edges
+from repro.kernels import backend
 from repro.kernels.ph_phase_c import kernel, ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def best_edge_reduce(key, ra, rb, nv: int, *, block_edges: int = 1024,
                      use_pallas: bool | None = None,
                      interpret: bool = False):
-    """Per-cluster best incident edge, Pallas or XLA backend.
-
-    ``use_pallas=None`` auto-selects: the Pallas kernel on TPU, the XLA
-    reference elsewhere (on CPU the fused win comes from the compact
-    instance, not from emulating the kernel).  Forcing ``use_pallas=True``
-    off-TPU runs the kernel in interpret mode (CI's parity path).
+    """Per-cluster best incident edge, Pallas or XLA backend, chosen by
+    :func:`repro.kernels.backend.resolve` (on CPU the fused win comes
+    from the compact instance, not from emulating the kernel;
+    ``interpret=True`` is CI's parity path).
     """
-    if use_pallas is None:
-        use_pallas = _on_tpu()
-    if not use_pallas:
+    impl = backend.resolve("ph_phase_c", use_pallas, interpret)
+    if impl == backend.XLA:
         return ref.best_edge_reduce(key, ra, rb, nv)
     return kernel.best_edge_reduce(key, ra, rb, nv,
                                    block_edges=block_edges,
-                                   interpret=interpret or not _on_tpu())
+                                   interpret=impl == backend.INTERPRET)
 
 
 def _compact_mask(key_flat, mask, k: int):

@@ -92,8 +92,6 @@ def _analyze(compiled, cfg, shape) -> dict:
                               - ma.alias_size_in_bytes),
     }
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, list):             # older jax: one dict per device
-        ca = ca[0] if ca else {}
     out["cost_analysis"] = {"flops": float(ca.get("flops", 0.0)),
                             "bytes_accessed":
                                 float(ca.get("bytes accessed", 0.0))}

@@ -20,6 +20,7 @@ import json
 
 import numpy as np
 
+from repro.launch.compile_cache import setup_compile_cache
 from repro.ph import PHConfig, PHEngine
 
 
@@ -44,11 +45,13 @@ def main():
     ap.add_argument("--max-candidates", type=int, default=32768)
     ap.add_argument("--use-pallas", dest="use_pallas", action="store_true",
                     default=None,
-                    help="force the Pallas distance kernel (interpret "
-                         "mode off-TPU)")
+                    help="force the Pallas distance kernel (it has no "
+                         "Mosaic lowering: with --interpret it runs in the "
+                         "Pallas interpreter, on TPU the XLA path runs)")
     ap.add_argument("--interpret", action="store_true")
     ap.add_argument("--out", help="write {sw, bottleneck} matrices as .npz")
     args = ap.parse_args()
+    setup_compile_cache()
 
     config = PHConfig.from_flags(args)
     engine = PHEngine(config)

@@ -28,6 +28,7 @@ from __future__ import annotations
 import argparse
 import json
 
+from repro.launch.compile_cache import setup_compile_cache
 from repro.pipeline.driver import FailureInjector
 from repro.ph import PHConfig, PHEngine
 
@@ -125,6 +126,7 @@ def main():
                     help="route images above this pixel count through the "
                          "tiled path (also the auto-grid tile budget)")
     args = ap.parse_args()
+    setup_compile_cache()
     if args.max_tile_pixels is None and (
             args.tile_grid or args.tile_max_features
             or args.tile_max_candidates):

@@ -22,6 +22,7 @@ import threading
 
 import numpy as np
 
+from repro.launch.compile_cache import setup_compile_cache
 from repro.ph import PHConfig, PHEngine
 from repro.serving import AdmissionError, PHServer
 
@@ -78,6 +79,7 @@ def main():
                     help="skip plan pre-tracing (show cold-start traces)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    setup_compile_cache()
     args.serve = True
 
     config = PHConfig.from_flags(args)

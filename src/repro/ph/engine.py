@@ -38,6 +38,7 @@ from repro.core import Diagram, batched_pixhomology, diagram_to_array, \
 from repro.core.packed_keys import check_finite, key_scope, \
     resolve_merge_keys
 from repro.distributed.context import shard_map_compat
+from repro.kernels import backend
 from repro.ph.config import FilterLevel, OverlapSpec, PHConfig, TileSpec
 from repro.ph.overlap import OverlapCounters, PendingResult, start_d2h
 
@@ -68,7 +69,9 @@ class Plan:
     plans trace, lower, and execute inside the int64
     :func:`repro.core.packed_keys.key_scope` — the scope must wrap the
     outermost jit call, which is exactly what ``__call__``/:meth:`lower`
-    are.
+    are.  ``impls`` records what the plan's program runs: the key
+    encoding and, per kernel package it reaches, the resolved backend
+    (:func:`repro.kernels.backend.resolve`).
 
     Thread safety: concurrent submitters (the serving daemon, the hammer
     regression test) may race into one plan.  The *first* call — the one
@@ -78,14 +81,17 @@ class Plan:
     calls run concurrently.
     """
 
-    __slots__ = ("fn", "key", "traces", "calls", "merge_keys", "_lock")
+    __slots__ = ("fn", "key", "traces", "calls", "merge_keys", "impls",
+                 "_lock")
 
-    def __init__(self, fn: Callable, key: tuple, merge_keys: str = "rank"):
+    def __init__(self, fn: Callable, key: tuple, merge_keys: str = "rank",
+                 impls: dict | None = None):
         self.fn = fn
         self.key = key
         self.traces = 0
         self.calls = 0
         self.merge_keys = merge_keys
+        self.impls = impls if impls is not None else {}
         self._lock = threading.Lock()
 
     def __call__(self, *args):
@@ -193,7 +199,8 @@ class PHEngine:
         with self._lock:
             plan = self._plans.get(key)
             if plan is None:
-                plan = Plan(None, key, merge_keys)
+                plan = Plan(None, key, merge_keys,
+                            self._plan_impls(key[0], merge_keys))
                 plan.fn = builder(plan)
                 self._plans[key] = plan
                 self._misses += 1
@@ -201,9 +208,41 @@ class PHEngine:
                 self._hits += 1
             return plan
 
+    def _plan_impls(self, kind: str, merge_keys: str) -> dict:
+        """What a plan of ``kind`` runs under this config: the resolved
+        key encoding plus the backend of each kernel package its program
+        reaches.  The tiled seam merge passes no backend toggles to the
+        phase-C reduction, so it takes the backend default."""
+        cfg = self.config
+
+        def res(kernel, use_pallas=cfg.use_pallas, interpret=cfg.interpret):
+            return backend.resolve(kernel, use_pallas, interpret)
+
+        impls = {"merge_keys": merge_keys}
+        if kind == "distance":
+            impls["ph_distance"] = res("ph_distance")
+        elif kind in ("single", "batched", "sharded"):
+            if cfg.phase_a_impl == "fused":
+                impls["ph_phase_a"] = res("ph_phase_a")
+            if cfg.phase_a_impl == "pooled" or cfg.candidate_mode == "paper":
+                impls["maxpool"] = res("maxpool")
+            if cfg.merge_impl == "boruvka" and cfg.phase_c_impl == "fused":
+                impls["ph_phase_c"] = res("ph_phase_c")
+        elif kind != "delta_ab" and cfg.phase_c_impl == "fused":
+            impls["ph_phase_c"] = res("ph_phase_c", None, False)
+        return impls
+
     def plan_stats(self) -> dict:
+        """Plan-cache counters, plus ``impls``: per plan kind, what its
+        programs run (:attr:`Plan.impls`; values that differ between
+        plans of one kind, e.g. per dtype, are joined with ``|``)."""
         with self._lock:
             plans = list(self._plans.values())
+            impls: dict[str, dict[str, set]] = {}
+            for p in plans:
+                per = impls.setdefault(p.key[0], {})
+                for name, impl in p.impls.items():
+                    per.setdefault(name, set()).add(impl)
             return {
                 "plans": len(plans),
                 "traces": sum(p.traces for p in plans),
@@ -211,6 +250,9 @@ class PHEngine:
                 "hits": self._hits,
                 "misses": self._misses,
                 "regrows": len(self.regrow_log),
+                "impls": {kind: {name: "|".join(sorted(v))
+                                 for name, v in per.items()}
+                          for kind, per in impls.items()},
             }
 
     # -- overlap policy ----------------------------------------------------

@@ -103,6 +103,7 @@ def run_pipeline(pool, images, *, strategy: str = "part_LPT",
     failures = 0
     rounds = 0
     attempt = 0
+    last_error: RuntimeError | None = None
     prefetch = max(0, int(getattr(pool, "prefetch_rounds", 0)))
     ospec = getattr(pool, "overlap", None)
     overlapped = (ospec is not None and ospec.enabled
@@ -200,7 +201,10 @@ def run_pipeline(pool, images, *, strategy: str = "part_LPT",
                 fut, rnd_done = harvest_q.pop(0)
                 record(rnd_done, fut.result())
         except RuntimeError as e:
+            # Injected failures and JAX runtime/compile errors alike
+            # (both subclass RuntimeError): retry, keep the cause.
             failures += 1
+            last_error = e
             if verbose:
                 print(f"FAILURE (attempt {attempt}): {e}; "
                       f"re-scheduling incomplete images", flush=True)
@@ -230,5 +234,5 @@ def run_pipeline(pool, images, *, strategy: str = "part_LPT",
 
     if pending:
         raise RuntimeError(f"pipeline could not finish {len(pending)} images "
-                           f"after {max_retries} retries")
+                           f"after {max_retries} retries") from last_error
     return PipelineResult(done, rounds, failures, time.time() - t0)

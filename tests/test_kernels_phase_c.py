@@ -54,7 +54,8 @@ def _instance(e: int, nv: int, dtype, seed: int, dead_frac: float = 0.3):
 
 
 @pytest.mark.parametrize("e,nv,block", [(1, 1, 4), (7, 3, 4), (33, 4, 8),
-                                        (64, 5, 16), (100, 9, 1024)])
+                                        (64, 5, 16), (100, 9, 1024),
+                                        (3000, 37, 200)])
 @pytest.mark.parametrize("dtype", ["int32", "int64"])
 def test_kernel_matches_ref(e, nv, block, dtype):
     scope = "packed" if dtype == "int64" else "rank"

@@ -163,6 +163,41 @@ def test_plan_cache_distinct_shapes_get_distinct_plans():
     assert stats["plans"] == 2 and stats["traces"] == 2
 
 
+@pytest.mark.parametrize("use_pallas,interpret,tpu,want", [
+    (None, False, True, "pallas"), (None, False, False, "xla"),
+    (True, False, True, "pallas"), (False, False, True, "xla"),
+    (None, True, False, "interpret"), (True, True, True, "interpret"),
+    (False, True, False, "xla")])
+def test_backend_resolution_table(monkeypatch, use_pallas, interpret, tpu,
+                                  want):
+    from repro.kernels import backend
+    monkeypatch.setattr(backend, "on_tpu", lambda: tpu)
+    assert backend.resolve("ph_phase_a", use_pallas, interpret) == want
+    # A kernel Mosaic cannot lower runs its named XLA path on TPU.
+    dist = "xla" if want == "pallas" else want
+    assert backend.resolve("ph_distance", use_pallas, interpret) == dist
+
+
+def test_backend_forced_pallas_off_tpu_is_an_error(monkeypatch):
+    from repro.kernels import backend
+    monkeypatch.setattr(backend, "on_tpu", lambda: False)
+    with pytest.raises(ValueError, match="interpret=True"):
+        backend.resolve("ph_phase_c", True, False)
+
+
+def test_plan_stats_reports_resolved_impls():
+    engine = PHEngine(PHConfig(max_features=256, max_candidates=256,
+                               merge_impl="boruvka"))
+    engine.run(_bumpy(0))
+    assert engine.plan_stats()["impls"] == {"single": {
+        "merge_keys": "packed", "ph_phase_a": "xla", "ph_phase_c": "xla"}}
+    interp = PHEngine(PHConfig(max_features=256, max_candidates=256,
+                               interpret=True))
+    interp.run(_bumpy(0, (8, 8)))
+    assert interp.plan_stats()["impls"]["single"]["ph_phase_a"] == \
+        "interpret"
+
+
 def test_batched_plan_reused():
     engine = PHEngine(PHConfig(max_features=128, max_candidates=128))
     imgs = np.stack([_bumpy(s, (10, 11)) for s in range(4)])
@@ -308,3 +343,31 @@ def test_run_distributed_smoke_and_regrow():
     assert len(res.diagrams) == 2
     assert all(not d["overflow"] for d in res.diagrams.values())
     assert engine.plan_stats()["regrows"] >= 1
+
+
+# ---------------------------------------------------------------------------
+# Persistent compile cache placement (launchers and chip_smoke.py)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("env_dir", [None, "/cache/from/env"])
+def test_compile_cache_placement(monkeypatch, env_dir):
+    import jax
+    from repro.launch import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    try:
+        got = compile_cache.setup_compile_cache()
+        if env_dir is None:
+            want = str(compile_cache.CHECKOUT / ".jax_cache")
+            assert got == want
+            assert jax.config.jax_compilation_cache_dir == want
+            assert (compile_cache.CHECKOUT / "chip_smoke.py").exists()
+        else:
+            # JAX reads the variable itself; nothing else is set in code.
+            assert got == env_dir
+            assert jax.config.jax_compilation_cache_dir == before
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -184,6 +184,24 @@ def test_failure_recovery(pool):
     assert len(res.diagrams) == 3    # everything still computed
 
 
+def test_exhausted_retries_chain_the_device_error(pool, monkeypatch):
+    """A non-injected failure (a compile error raised from the executor)
+    is retried like an injected one, and once the retries are exhausted
+    the final error carries it as its cause."""
+    import jax
+
+    def refuse(staged):
+        raise jax.errors.JaxRuntimeError(
+            "INTERNAL: Mosaic failed to compile TPU kernel")
+
+    monkeypatch.setattr(pool, "run_staged", refuse)
+    with pytest.raises(RuntimeError, match="could not finish") as info:
+        run_pipeline(pool, [0, 1], max_retries=2)
+    cause = info.value.__cause__
+    assert isinstance(cause, jax.errors.JaxRuntimeError)
+    assert "Mosaic failed to compile" in str(cause)
+
+
 def test_worklog_resume(tmp_path, pool):
     log = tmp_path / "work.jsonl"
     res1 = run_pipeline(pool, [0, 1], work_log=log)

@@ -19,8 +19,6 @@ def test_scan_flops_expanded():
     comp = jax.jit(scanned).lower(
         jax.ShapeDtypeStruct((128, 128), jnp.float32)).compile()
     ca = comp.cost_analysis()
-    if isinstance(ca, list):             # older jax: one dict per device
-        ca = ca[0]
     summ = analysis.analyze_hlo(comp.as_text())
     per_matmul = 2 * 128 ** 3
     assert abs(ca["flops"] - per_matmul) / per_matmul < 0.01   # XLA: once
