@@ -1,0 +1,22 @@
+"""Run one benchmark cell on the chips of this machine.
+
+    python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
+
+Prints the set-up parts and each compared number on standard error, and
+one JSON result as the last line of standard output.  Exits non-zero,
+with no result, where JAX finds no TPU or fewer chips than the cell asks.
+"""
+import time
+
+T_START = time.perf_counter()
+
+import sys  # noqa: E402
+from pathlib import Path  # noqa: E402
+
+ROOT = Path(__file__).resolve().parents[1]
+sys.path[:0] = [str(ROOT), str(ROOT / "src")]
+
+from bench.harness import main  # noqa: E402
+
+if __name__ == "__main__":
+    sys.exit(main(T_START))
