@@ -56,16 +56,18 @@ def candidate_edges(key_flat, labels_flat, cand_flat, shape,
     n = h * w
     k = min(max_candidates, n)
     pad = key_pad(key_flat.dtype)
-    top_keys, top_pix = masked_top_k(key_flat, cand_flat, k,
-                                     tournament_width)
-    valid = top_keys > pad
-    ok, lbl = higher_neighbor_basins(top_pix, top_keys, key_flat,
-                                     labels_flat, shape, valid)  # (K, 8)
-    edge_ok, prev_lbl = chain_clique_edges(ok, lbl)
-    keys = jnp.broadcast_to(top_keys[:, None], ok.shape)
-    return (jnp.where(edge_ok, keys, pad).reshape(-1),
-            jnp.where(edge_ok, lbl, 0).reshape(-1),
-            jnp.where(edge_ok, prev_lbl, 0).reshape(-1))
+    with jax.named_scope("ph.select"):
+        top_keys, top_pix = masked_top_k(key_flat, cand_flat, k,
+                                         tournament_width)
+    with jax.named_scope("ph.merge"):
+        valid = top_keys > pad
+        ok, lbl = higher_neighbor_basins(top_pix, top_keys, key_flat,
+                                         labels_flat, shape, valid)  # (K, 8)
+        edge_ok, prev_lbl = chain_clique_edges(ok, lbl)
+        keys = jnp.broadcast_to(top_keys[:, None], ok.shape)
+        return (jnp.where(edge_ok, keys, pad).reshape(-1),
+                jnp.where(edge_ok, lbl, 0).reshape(-1),
+                jnp.where(edge_ok, prev_lbl, 0).reshape(-1))
 
 
 def chain_clique_edges(ok: jnp.ndarray, lbl: jnp.ndarray):
@@ -128,6 +130,7 @@ def best_edge_reduce(key, ra, rb, nv: int):
     return best, win
 
 
+@jax.named_scope("ph.merge")
 def boruvka_forest(v_rank, e_rank, e_val, e_pos, e_a, e_b, *,
                    n_live=None, reduce_fn=None):
     """Elder-rule Boruvka forest over an abstract vertex/edge instance.
@@ -231,12 +234,13 @@ def boruvka_merge(image_flat, key_flat, labels_flat, cand_flat, shape,
                                       shape, max_candidates,
                                       tournament_width)
     # Map the saddle key back to its pixel id for death values/positions.
-    if key_flat.dtype == jnp.int64:
-        e_pos = jnp.clip(packed_index(e_key), 0)     # pad keys -> pixel 0
-    else:
-        perm = jnp.argsort(key_flat, stable=True)    # rank -> pixel id
-        e_pos = perm[jnp.clip(e_key, 0)]
-    e_val = image_flat[e_pos]
+    with jax.named_scope("ph.merge"):
+        if key_flat.dtype == jnp.int64:
+            e_pos = jnp.clip(packed_index(e_key), 0)   # pad keys -> pixel 0
+        else:
+            perm = jnp.argsort(key_flat, stable=True)  # rank -> pixel id
+            e_pos = perm[jnp.clip(e_key, 0)]
+        e_val = image_flat[e_pos]
 
     dval, dpos, rounds = boruvka_forest(key_flat, e_key, e_val, e_pos,
                                         e_a, e_b, n_live=n_live,

@@ -94,6 +94,9 @@ class Diagram(NamedTuple):
     count: jnp.ndarray     # () int32 number of valid rows (components found)
     n_unmerged: jnp.ndarray  # () int32 roots that never died (0 when exact)
     overflow: jnp.ndarray  # () bool: capacity exceeded -> retry with bigger F/K
+    # () int32 candidates the merge swept, after the Variant-2 mask (may
+    # exceed max_candidates: overflow); None on hand-built diagrams.
+    n_candidates: jnp.ndarray | None = None
 
 
 class PhaseA(NamedTuple):
@@ -129,10 +132,11 @@ def total_order_keys(values_flat: jnp.ndarray,
     encodings compare identically under ``>``; phase C never uses any
     other operation on them.
     """
-    if merge_keys == "packed":
-        return packed_keys.pack_keys(values_flat)
-    if merge_keys == "rank":
-        return total_order_rank(values_flat)
+    with jax.named_scope("ph.keys"):
+        if merge_keys == "packed":
+            return packed_keys.pack_keys(values_flat)
+        if merge_keys == "rank":
+            return total_order_rank(values_flat)
     raise ValueError(f"unknown merge_keys {merge_keys!r}")
 
 
@@ -143,9 +147,10 @@ def total_order_keys(values_flat: jnp.ndarray,
 def steepest_neighbors(image: jnp.ndarray, *, use_pallas: bool | None = None,
                        interpret: bool = False) -> jnp.ndarray:
     """arg-maxpool2d(I): flat index of each pixel's 3x3 max (paper line 1)."""
-    _, arg = pool_ops.maxargmaxpool3x3(image, use_pallas=use_pallas,
-                                       interpret=interpret)
-    return arg.reshape(-1)
+    with jax.named_scope("ph.phase_a"):
+        _, arg = pool_ops.maxargmaxpool3x3(image, use_pallas=use_pallas,
+                                           interpret=interpret)
+        return arg.reshape(-1)
 
 
 def keyed_steepest_pointers(values2d: jnp.ndarray,
@@ -162,14 +167,15 @@ def keyed_steepest_pointers(values2d: jnp.ndarray,
     flat = jnp.arange(h * w, dtype=jnp.int32).reshape(h, w)
     fill_v = neg_inf(values2d.dtype)
     best_v, best_k, best_l = values2d, keys2d, flat
-    for dr, dc in NEIGHBOR_OFFSETS:
-        v = shift2d(values2d, dr, dc, fill_v)
-        k = shift2d(keys2d, dr, dc, jnp.int32(-1))
-        l = shift2d(flat, dr, dc, jnp.int32(-1))
-        better = (v > best_v) | ((v == best_v) & (k > best_k))
-        best_v = jnp.where(better, v, best_v)
-        best_k = jnp.where(better, k, best_k)
-        best_l = jnp.where(better, l, best_l)
+    with jax.named_scope("ph.phase_a"):
+        for dr, dc in NEIGHBOR_OFFSETS:
+            v = shift2d(values2d, dr, dc, fill_v)
+            k = shift2d(keys2d, dr, dc, jnp.int32(-1))
+            l = shift2d(flat, dr, dc, jnp.int32(-1))
+            better = (v > best_v) | ((v == best_v) & (k > best_k))
+            best_v = jnp.where(better, v, best_v)
+            best_k = jnp.where(better, k, best_k)
+            best_l = jnp.where(better, l, best_l)
     return best_l
 
 
@@ -207,7 +213,8 @@ def resolve_labels(pointers: jnp.ndarray, *, with_count: bool = False):
     whole-array gather (the changed flag rides the carry instead of
     re-gathering in ``cond`` — DESIGN.md §Perf PH-3).
     """
-    m, count = fixed_point_iterate(lambda q: q[q], pointers)
+    with jax.named_scope("ph.phase_b"):
+        m, count = fixed_point_iterate(lambda q: q[q], pointers)
     return (m, count) if with_count else m
 
 
@@ -226,20 +233,21 @@ def resolve_labels_frontier(pointers: jnp.ndarray, shape: tuple[int, int],
     b_rows = phase_a_ops.boundary_rows(h, strip_rows)
     row_slot_np = np.full(h, -1, np.int32)
     row_slot_np[b_rows] = np.arange(len(b_rows), dtype=np.int32)
-    row_slot = jnp.asarray(row_slot_np)
-    # Built from the (h / strip_rows)-entry row list on the device: as a
-    # host constant the O(n / strip_rows) table bloats every compile.
-    b_flat = (jnp.asarray(b_rows)[:, None] * jnp.int32(w)
-              + jnp.arange(w, dtype=jnp.int32)[None, :]).reshape(-1)
 
     def follow(table, q):
         rs = row_slot[q // w]
         slot = rs * w + q % w
         return jnp.where(rs >= 0, table[jnp.clip(slot, 0)], q)
 
-    p0 = pointers[b_flat]
-    table, count = fixed_point_iterate(lambda p: follow(p, p), p0)
-    labels = follow(table, pointers)
+    with jax.named_scope("ph.phase_b"):
+        row_slot = jnp.asarray(row_slot_np)
+        # Built from the (h / strip_rows)-entry row list on the device: as
+        # a host constant the O(n / strip_rows) table bloats every compile.
+        b_flat = (jnp.asarray(b_rows)[:, None] * jnp.int32(w)
+                  + jnp.arange(w, dtype=jnp.int32)[None, :]).reshape(-1)
+        p0 = pointers[b_flat]
+        table, count = fixed_point_iterate(lambda p: follow(p, p), p0)
+        labels = follow(table, pointers)
     return (labels, count) if with_count else labels
 
 
@@ -255,6 +263,7 @@ def phase_b(pa: PhaseA, shape: tuple[int, int], *,
 # Steps 3-4: candidate death points
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("ph.candidates")
 def exact_candidates(key2d: jnp.ndarray, labels2d: jnp.ndarray) -> jnp.ndarray:
     """Pixels whose strictly-higher 8-neighbors span >= 2 distinct basins.
 
@@ -280,6 +289,7 @@ def exact_candidates(key2d: jnp.ndarray, labels2d: jnp.ndarray) -> jnp.ndarray:
     return (hi_max >= 0) & (hi_max != hi_min)
 
 
+@jax.named_scope("ph.candidates")
 def exact_candidates_masked(hi_mask2d: jnp.ndarray,
                             labels2d: jnp.ndarray) -> jnp.ndarray:
     """:func:`exact_candidates` from phase A's higher-neighbor bitmask.
@@ -300,6 +310,7 @@ def exact_candidates_masked(hi_mask2d: jnp.ndarray,
     return (hi_max >= 0) & (hi_max != hi_min)
 
 
+@jax.named_scope("ph.candidates")
 def paper_candidates(key2d: jnp.ndarray, comp2d: jnp.ndarray,
                      *, use_pallas: bool | None = None,
                      interpret: bool = False) -> jnp.ndarray:
@@ -340,6 +351,7 @@ def paper_candidates(key2d: jnp.ndarray, comp2d: jnp.ndarray,
     return edge & (local_min | saddle)
 
 
+@jax.named_scope("ph.candidates")
 def reindex_components(key_flat: jnp.ndarray, labels_flat: jnp.ndarray,
                        is_root: jnp.ndarray) -> jnp.ndarray:
     """Paper step 2 re-indexing: component ids 0..C-1 ascending by birth.
@@ -370,9 +382,12 @@ def _find_vec(parent: jnp.ndarray, start: jnp.ndarray) -> jnp.ndarray:
 
 def merge_components(image_flat: jnp.ndarray, key_flat: jnp.ndarray,
                      labels_flat: jnp.ndarray, cand_flat: jnp.ndarray,
-                     shape: tuple[int, int], max_candidates: int,
-                     truncate_value=None):
+                     shape: tuple[int, int], max_candidates: int):
     """Process candidates in descending (value, index) order, union-find merge.
+
+    ``cand_flat`` arrives with any Variant-2 mask applied (paper §5.2.1:
+    merges below the threshold never run; :func:`phase_c` truncates the
+    survivors at the threshold).
 
     ``key_flat``: dense int32 ranks or packed int64 keys — the sweep only
     compares them.  On packed keys the top-k selection runs as a
@@ -389,14 +404,10 @@ def merge_components(image_flat: jnp.ndarray, key_flat: jnp.ndarray,
     k = min(max_candidates, n)
     pad = key_pad(key_flat.dtype)
 
-    if truncate_value is not None:
-        # Variant 2 (paper §5.2.1): sub-threshold pixels are excluded from
-        # the analysis — merges below the threshold never run; the survivors
-        # are truncated at the threshold by the caller.
-        cand_flat = cand_flat & (image_flat >= truncate_value)
-    n_cand = jnp.sum(cand_flat, dtype=jnp.int32)
-    top_keys, top_pix = masked_top_k(key_flat, cand_flat, k)  # descending
-    overflow = n_cand > k
+    with jax.named_scope("ph.select"):
+        n_cand = jnp.sum(cand_flat, dtype=jnp.int32)
+        top_keys, top_pix = masked_top_k(key_flat, cand_flat, k)  # desc.
+        overflow = n_cand > k
 
     neg_inf = (-jnp.inf if jnp.issubdtype(image_flat.dtype, jnp.floating)
                else jnp.iinfo(image_flat.dtype).min)
@@ -428,11 +439,12 @@ def merge_components(image_flat: jnp.ndarray, key_flat: jnp.ndarray,
         dpos = dpos.at[jnp.where(die, roots, drop)].set(x, mode="drop")
         return (parent, dval, dpos), None
 
-    parent0 = jnp.arange(n, dtype=jnp.int32)
-    dval0 = jnp.full(n, neg_inf, image_flat.dtype)
-    dpos0 = jnp.full(n, -1, jnp.int32)
-    (parent, dval, dpos), _ = jax.lax.scan(
-        step, (parent0, dval0, dpos0), (top_pix, top_keys))
+    with jax.named_scope("ph.merge"):
+        parent0 = jnp.arange(n, dtype=jnp.int32)
+        dval0 = jnp.full(n, neg_inf, image_flat.dtype)
+        dpos0 = jnp.full(n, -1, jnp.int32)
+        (parent, dval, dpos), _ = jax.lax.scan(
+            step, (parent0, dval0, dpos0), (top_pix, top_keys))
     del parent
     return dval, dpos, overflow
 
@@ -467,89 +479,96 @@ def phase_c(image_flat: jnp.ndarray, key_flat: jnp.ndarray,
     h, w = shape
     n = h * w
     vals = image_flat
-    is_root = labels_flat == jnp.arange(n, dtype=jnp.int32)
     f = min(max_features, n)
     neg_inf = (-jnp.inf if jnp.issubdtype(vals.dtype, jnp.floating)
                else jnp.iinfo(vals.dtype).min)
-    gmax = jnp.argmax(key_flat).astype(jnp.int32)
-    gmin = jnp.argmin(key_flat).astype(jnp.int32)
-    root_mask = is_root if truncate_value is None else \
-        is_root & (vals >= truncate_value)
+    with jax.named_scope("ph.diagram"):
+        is_root = labels_flat == jnp.arange(n, dtype=jnp.int32)
+        gmax = jnp.argmax(key_flat).astype(jnp.int32)
+        gmin = jnp.argmin(key_flat).astype(jnp.int32)
+        root_mask = is_root if truncate_value is None else \
+            is_root & (vals >= truncate_value)
+    with jax.named_scope("ph.select"):
+        # The candidates every merge sweeps: the Variant-2 mask (paper
+        # §5.2.1) applied once here, their count carried out as
+        # ``n_candidates``.
+        if truncate_value is not None:
+            cand_flat = cand_flat & (vals >= truncate_value)
+        n_cand = jnp.sum(cand_flat, dtype=jnp.int32)
 
     if merge_impl == "boruvka" and phase_c_impl == "fused":
         # Compact fused path: merge + diagram read the same top-f root
         # table, so deaths never materialize in the pixel domain at all.
         from repro.kernels.ph_phase_c import ops as phase_c_ops
-        cand_b = cand_flat if truncate_value is None else \
-            cand_flat & (vals >= truncate_value)
         (_, root_pix, rvalid, dval_c, dpos_c, overflow_k,
          _rounds) = phase_c_ops.fused_merge(
-            vals, key_flat, labels_flat, cand_b, root_mask, (h, w),
+            vals, key_flat, labels_flat, cand_flat, root_mask, (h, w),
             max_candidates=max_candidates, max_features=max_features,
             phase_c_block=phase_c_block, tournament_width=tournament_width,
             use_pallas=use_pallas, interpret=interpret)
-        if truncate_value is not None:
-            undied_c = rvalid & (dpos_c < 0)
-            dval_c = jnp.where(undied_c,
-                               jnp.asarray(truncate_value, dval_c.dtype),
-                               dval_c)
-        # Essential class on the compact table: slot 0 is the global
-        # maximum's root whenever any root exists (paper fig 3).
-        dval_c = dval_c.at[0].set(
-            jnp.where(rvalid[0], vals[gmin], dval_c[0]))
-        dpos_c = dpos_c.at[0].set(jnp.where(rvalid[0], gmin, dpos_c[0]))
+        with jax.named_scope("ph.diagram"):
+            if truncate_value is not None:
+                undied_c = rvalid & (dpos_c < 0)
+                dval_c = jnp.where(
+                    undied_c, jnp.asarray(truncate_value, dval_c.dtype),
+                    dval_c)
+            # Essential class on the compact table: slot 0 is the global
+            # maximum's root whenever any root exists (paper fig 3).
+            dval_c = dval_c.at[0].set(
+                jnp.where(rvalid[0], vals[gmin], dval_c[0]))
+            dpos_c = dpos_c.at[0].set(
+                jnp.where(rvalid[0], gmin, dpos_c[0]))
 
-        c = jnp.sum(root_mask, dtype=jnp.int32)
-        row_valid = jnp.arange(f) < c
-        birth = jnp.where(row_valid, vals[root_pix], neg_inf)
-        death = jnp.where(row_valid, dval_c, neg_inf)
-        p_birth = jnp.where(row_valid, root_pix, -1).astype(jnp.int32)
-        p_death = jnp.where(row_valid, dpos_c, -1).astype(jnp.int32)
-        n_unmerged = jnp.sum(rvalid & (dpos_c < 0), dtype=jnp.int32)
-        overflow = overflow_k | (c > f)
+            c = jnp.sum(root_mask, dtype=jnp.int32)
+            row_valid = jnp.arange(f) < c
+            birth = jnp.where(row_valid, vals[root_pix], neg_inf)
+            death = jnp.where(row_valid, dval_c, neg_inf)
+            p_birth = jnp.where(row_valid, root_pix, -1).astype(jnp.int32)
+            p_death = jnp.where(row_valid, dpos_c, -1).astype(jnp.int32)
+            n_unmerged = jnp.sum(rvalid & (dpos_c < 0), dtype=jnp.int32)
+            overflow = overflow_k | (c > f)
         return Diagram(birth, death, p_birth, p_death,
-                       jnp.minimum(c, f), n_unmerged, overflow)
+                       jnp.minimum(c, f), n_unmerged, overflow, n_cand)
 
     if merge_impl == "scan":
         dval, dpos, overflow_k = merge_components(
-            vals, key_flat, labels_flat, cand_flat, (h, w), max_candidates,
-            truncate_value=truncate_value)
+            vals, key_flat, labels_flat, cand_flat, (h, w), max_candidates)
     elif merge_impl == "boruvka":
         from repro.core import parallel_merge
-        cand_b = cand_flat if truncate_value is None else \
-            cand_flat & (vals >= truncate_value)
         dval, dpos, overflow_k, _rounds = parallel_merge.boruvka_merge(
-            vals, key_flat, labels_flat, cand_b, (h, w), max_candidates,
+            vals, key_flat, labels_flat, cand_flat, (h, w), max_candidates,
             n_live=jnp.sum(root_mask, dtype=jnp.int32),
             tournament_width=tournament_width)
     else:
         raise ValueError(f"unknown merge_impl {merge_impl!r}")
 
-    if truncate_value is not None:
-        # Sub-threshold components are background; survivors die at t.
-        is_root = root_mask
-        undied = is_root & (dpos < 0)
-        dval = jnp.where(undied, jnp.asarray(truncate_value, dval.dtype),
-                         dval)
+    with jax.named_scope("ph.diagram"):
+        if truncate_value is not None:
+            # Sub-threshold components are background; survivors die at t.
+            is_root = root_mask
+            undied = is_root & (dpos < 0)
+            dval = jnp.where(undied,
+                             jnp.asarray(truncate_value, dval.dtype), dval)
 
-    # Essential class: global maximum dies at the global minimum (paper fig 3).
-    dval = dval.at[gmax].set(vals[gmin])
-    dpos = dpos.at[gmax].set(gmin)
+        # Essential class: global maximum dies at the global minimum
+        # (paper fig 3).
+        dval = dval.at[gmax].set(vals[gmin])
+        dpos = dpos.at[gmax].set(gmin)
 
-    # Step 6: persistence diagram, descending by birth.
-    _, root_pix = masked_top_k(key_flat, is_root, f, tournament_width)
-    row_valid = jnp.arange(f) < jnp.sum(is_root, dtype=jnp.int32)
+        # Step 6: persistence diagram, descending by birth.
+        _, root_pix = masked_top_k(key_flat, is_root, f, tournament_width)
+        row_valid = jnp.arange(f) < jnp.sum(is_root, dtype=jnp.int32)
 
-    birth = jnp.where(row_valid, vals[root_pix], neg_inf)
-    death = jnp.where(row_valid, dval[root_pix], neg_inf)
-    p_birth = jnp.where(row_valid, root_pix, -1).astype(jnp.int32)
-    p_death = jnp.where(row_valid, dpos[root_pix], -1).astype(jnp.int32)
+        birth = jnp.where(row_valid, vals[root_pix], neg_inf)
+        death = jnp.where(row_valid, dval[root_pix], neg_inf)
+        p_birth = jnp.where(row_valid, root_pix, -1).astype(jnp.int32)
+        p_death = jnp.where(row_valid, dpos[root_pix], -1).astype(jnp.int32)
 
-    c = jnp.sum(is_root, dtype=jnp.int32)
-    n_unmerged = jnp.sum(is_root & (dpos < 0), dtype=jnp.int32)
-    overflow = overflow_k | (c > f)
+        c = jnp.sum(is_root, dtype=jnp.int32)
+        n_unmerged = jnp.sum(is_root & (dpos < 0), dtype=jnp.int32)
+        overflow = overflow_k | (c > f)
     return Diagram(birth, death, p_birth, p_death,
-                   jnp.minimum(c, f), n_unmerged, overflow)
+                   jnp.minimum(c, f), n_unmerged, overflow, n_cand)
 
 
 # ---------------------------------------------------------------------------

@@ -400,39 +400,45 @@ def tile_phase_b(pvals, pgidx, ptr_owned, tv, *,
     plbl = jnp.where(interior, jnp.pad(ptr_owned, 1, constant_values=-1),
                      jnp.where(pgidx >= 0, pgidx, -1))
 
-    if merge_keys == "packed":
-        # Packed (value, global index) keys are order-isomorphic to the
-        # global total order on the padded tile directly — no sort.  Halo
-        # fill cells (value -inf/int-min, gidx -1) pack low word 0: below
-        # every real pixel (for integer dtype-min fills they reach the
-        # pad sentinel itself, which is fine — halo cells are excluded by
-        # the interior mask, never by key comparison).
-        key = pack_keys(pvals.reshape(-1), pgidx.reshape(-1))
-    else:
-        # Per-tile rank, order-isomorphic to the global (value, index)
-        # order (halo fill keys (-inf, -1) sort strictly below every real
-        # pixel).
-        order = jnp.lexsort((pgidx.reshape(-1), pvals.reshape(-1)))
-        key = jnp.zeros(n_loc, jnp.int32).at[order].set(
-            jnp.arange(n_loc, dtype=jnp.int32))
+    with jax.named_scope("ph.keys"):
+        if merge_keys == "packed":
+            # Packed (value, global index) keys are order-isomorphic to
+            # the global total order on the padded tile directly — no
+            # sort.  Halo fill cells (value -inf/int-min, gidx -1) pack
+            # low word 0: below every real pixel (for integer dtype-min
+            # fills they reach the pad sentinel itself, which is fine —
+            # halo cells are excluded by the interior mask, never by key
+            # comparison).
+            key = pack_keys(pvals.reshape(-1), pgidx.reshape(-1))
+        else:
+            # Per-tile rank, order-isomorphic to the global (value, index)
+            # order (halo fill keys (-inf, -1) sort strictly below every
+            # real pixel).
+            order = jnp.lexsort((pgidx.reshape(-1), pvals.reshape(-1)))
+            key = jnp.zeros(n_loc, jnp.int32).at[order].set(
+                jnp.arange(n_loc, dtype=jnp.int32))
     pad = key_pad(key.dtype)
 
     cand2d = exact_candidates(key.reshape(ph, pw), plbl) & interior
-    if truncated:
-        cand2d &= pvals >= tv
-    cand_flat = cand2d.reshape(-1)
-    n_cand = jnp.sum(cand_flat, dtype=jnp.int32)
+    with jax.named_scope("ph.select"):
+        if truncated:
+            cand2d &= pvals >= tv
+        cand_flat = cand2d.reshape(-1)
+        n_cand = jnp.sum(cand_flat, dtype=jnp.int32)
+        k = min(tile_max_candidates, tr * tc)
+        top_keys, top_loc = masked_top_k(key, cand_flat, k)
 
-    k = min(tile_max_candidates, tr * tc)
-    top_keys, top_loc = masked_top_k(key, cand_flat, k)
-    valid = top_keys > pad
-    ok, lbl = higher_neighbor_basins(top_loc, top_keys, key,
-                                     plbl.reshape(-1), (ph, pw), valid)
-    edge_ok, prev_lbl = chain_clique_edges(ok, lbl)          # (k, 8)
-    e_val = jnp.broadcast_to(pvals.reshape(-1)[top_loc][:, None], ok.shape)
-    e_pos = jnp.broadcast_to(pgidx.reshape(-1)[top_loc][:, None], ok.shape)
-    e_a = jnp.where(edge_ok, lbl, 0)
-    e_b = jnp.where(edge_ok, prev_lbl, 0)
+    with jax.named_scope("ph.merge"):
+        valid = top_keys > pad
+        ok, lbl = higher_neighbor_basins(top_loc, top_keys, key,
+                                         plbl.reshape(-1), (ph, pw), valid)
+        edge_ok, prev_lbl = chain_clique_edges(ok, lbl)          # (k, 8)
+        e_val = jnp.broadcast_to(pvals.reshape(-1)[top_loc][:, None],
+                                 ok.shape)
+        e_pos = jnp.broadcast_to(pgidx.reshape(-1)[top_loc][:, None],
+                                 ok.shape)
+        e_a = jnp.where(edge_ok, lbl, 0)
+        e_b = jnp.where(edge_ok, prev_lbl, 0)
 
     # Basin roots owned by this tile.  Root-ness is tile-local: ascent
     # chains are strictly increasing in (value, index), so a pixel whose
@@ -450,11 +456,12 @@ def tile_phase_b(pvals, pgidx, ptr_owned, tv, *,
     n_roots = jnp.sum(root_mask, dtype=jnp.int32)
 
     f = min(tile_max_features, tr * tc)
-    own_key = key.reshape(ph, pw)[1:-1, 1:-1].reshape(-1)
-    top_rk, top_ri = masked_top_k(own_key, root_mask.reshape(-1), f)
-    rvalid = top_rk > pad
-    root_gidx = jnp.where(rvalid, own_gidx.reshape(-1)[top_ri], -1)
-    root_val = jnp.where(rvalid, own_vals.reshape(-1)[top_ri], fill_v)
+    with jax.named_scope("ph.diagram"):
+        own_key = key.reshape(ph, pw)[1:-1, 1:-1].reshape(-1)
+        top_rk, top_ri = masked_top_k(own_key, root_mask.reshape(-1), f)
+        rvalid = top_rk > pad
+        root_gidx = jnp.where(rvalid, own_gidx.reshape(-1)[top_ri], -1)
+        root_val = jnp.where(rvalid, own_vals.reshape(-1)[top_ri], fill_v)
 
     return (e_val, e_pos, e_a, e_b, edge_ok,
             root_val, root_gidx.astype(jnp.int32), rvalid,
@@ -620,6 +627,7 @@ def seam_merge(root_val, root_gidx, root_valid,
             merge_overflow)
 
 
+@jax.named_scope("ph.seam")
 def merge_tile_state(state: TileBoundaryState, tv, *,
                      shape: tuple[int, int], grid: tuple[int, int],
                      max_features: int, tile_max_features: int,
@@ -666,7 +674,8 @@ def merge_tile_state(state: TileBoundaryState, tv, *,
         jnp.any(state.n_cand > min(tile_max_candidates, tr * tc))
         | jnp.any(state.n_roots > min(tile_max_features, tr * tc)))
     diagram = Diagram(birth, death, p_birth, p_death, count, n_unmerged,
-                      tile_overflow | merge_overflow)
+                      tile_overflow | merge_overflow,
+                      jnp.sum(state.n_cand, dtype=jnp.int32))
     return TiledDiagram(diagram, tile_overflow, merge_overflow,
                         state.n_roots, state.n_cand)
 

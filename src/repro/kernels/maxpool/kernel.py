@@ -117,6 +117,7 @@ def _pool_call(x: jnp.ndarray, *, want_arg: bool, minimum: bool,
         out_shape=[jax.ShapeDtypeStruct((hp, w), x.dtype),
                    jax.ShapeDtypeStruct((hp, w), jnp.int32)],
         interpret=interpret,
+        name="maxpool",
     )(r0, r1, r2)
     return out_val[:h], out_arg[:h]
 

@@ -72,5 +72,6 @@ def distance_matrix(pts, diag, prof, *, interpret: bool = False):
         out_shape=[jax.ShapeDtypeStruct((b * b, 1, 128), pts.dtype),
                    jax.ShapeDtypeStruct((b * b, 1, 128), prof.dtype)],
         interpret=interpret,
+        name="distance",
     )(pts, diag, prof3, pts, diag, prof3)
     return sw[:, 0, 0].reshape(b, b), bn[:, 0, 0].reshape(b, b)

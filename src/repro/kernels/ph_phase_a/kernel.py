@@ -104,6 +104,7 @@ def _widen(image: jnp.ndarray) -> jnp.ndarray:
                         else jnp.int32)
 
 
+@jax.named_scope("ph.phase_a")
 def sweep(image: jnp.ndarray, *, interpret: bool = False):
     """The kernel alone: unsnapped pointers and mask, flat int32 — the
     Pallas twin of ``ref.pointer_and_mask_sweep``.  Every output pixel is
@@ -129,6 +130,7 @@ def sweep(image: jnp.ndarray, *, interpret: bool = False):
         out_shape=[jax.ShapeDtypeStruct((hp, w), jnp.int32),
                    jax.ShapeDtypeStruct((hp, w), jnp.int32)],
         interpret=interpret,
+        name="phase_a",
     )(r0, r1, r2)
     return hop[:h].reshape(-1), mask[:h].reshape(-1)
 

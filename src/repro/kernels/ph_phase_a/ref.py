@@ -34,6 +34,7 @@ from repro.core.grid import NEIGHBOR_OFFSETS, fixed_point_iterate, shift2d
 from repro.kernels.maxpool.ref import _neg_inf
 
 
+@jax.named_scope("ph.phase_a")
 def pointer_and_mask_sweep(image: jnp.ndarray
                            ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """One 8-offset sweep emitting (steepest pointer, higher bitmask).
@@ -73,6 +74,7 @@ def pointer_and_mask_sweep(image: jnp.ndarray
     return best_i, mask
 
 
+@jax.named_scope("ph.snap")
 def strip_snap(hop: jnp.ndarray, shape: tuple[int, int], strip_rows: int,
                *, with_stats: bool = False):
     """Snap flat steepest-ascent pointers ``hop`` to each pixel's furthest

@@ -158,6 +158,7 @@ def best_edge_reduce(key, ra, rb, nv: int, *, block_edges: int = 1024,
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=max(vmem, 32 << 20)),
         interpret=interpret,
+        name="phase_c",
     )(ra, rb, *words)
     flat = [o.reshape(-1)[:nv] for o in out]
     return _from_words(flat[:-1], key.dtype), flat[-1]

@@ -46,6 +46,7 @@ from __future__ import annotations
 
 import functools
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.grid import higher_neighbor_basins
@@ -104,17 +105,19 @@ def _compact_candidate_edges(key_flat, labels_flat, cand_flat, shape,
     h, w = shape
     k = min(max_candidates, h * w)
     pad = key_pad(key_flat.dtype)
-    top_keys, top_pix = _compact_mask(key_flat, cand_flat, k)
-    valid = top_keys > pad
-    ok, lbl = higher_neighbor_basins(top_pix, top_keys, key_flat,
-                                     labels_flat, shape, valid)  # (K, 8)
-    edge_ok, prev_lbl = chain_clique_edges(ok, lbl)
-    keys = jnp.broadcast_to(top_keys[:, None], ok.shape)
-    pixs = jnp.broadcast_to(top_pix[:, None], ok.shape)
-    return (jnp.where(edge_ok, keys, pad).reshape(-1),
-            jnp.where(edge_ok, lbl, 0).reshape(-1),
-            jnp.where(edge_ok, prev_lbl, 0).reshape(-1),
-            pixs.reshape(-1))
+    with jax.named_scope("ph.select"):
+        top_keys, top_pix = _compact_mask(key_flat, cand_flat, k)
+    with jax.named_scope("ph.merge"):
+        valid = top_keys > pad
+        ok, lbl = higher_neighbor_basins(top_pix, top_keys, key_flat,
+                                         labels_flat, shape, valid)  # (K, 8)
+        edge_ok, prev_lbl = chain_clique_edges(ok, lbl)
+        keys = jnp.broadcast_to(top_keys[:, None], ok.shape)
+        pixs = jnp.broadcast_to(top_pix[:, None], ok.shape)
+        return (jnp.where(edge_ok, keys, pad).reshape(-1),
+                jnp.where(edge_ok, lbl, 0).reshape(-1),
+                jnp.where(edge_ok, prev_lbl, 0).reshape(-1),
+                pixs.reshape(-1))
 
 
 def _slot_lookup(sorted_pix, order, q):
@@ -149,36 +152,38 @@ def fused_merge(image_flat, key_flat, labels_flat, cand_flat, root_mask,
     f = min(max_features, n)
     e_key, e_a, e_b, e_pos = _compact_candidate_edges(
         key_flat, labels_flat, cand_flat, shape, max_candidates)
-    e_val = image_flat[e_pos]
 
     # Compact vertex set: cumsum-compact the roots, then sort only the
     # f-length table into the diagram's descending key order (keys are
     # unique, so below capacity this equals the XLA ``masked_top_k``
     # selection exactly; pads sort to the tail).
-    rk_c, rp_c = _compact_mask(key_flat, root_mask, f)
-    order_desc = jnp.argsort(rk_c)[::-1].astype(jnp.int32)
-    root_key = rk_c[order_desc]
-    root_pix = rp_c[order_desc]
-    rvalid = root_key > key_pad(root_key.dtype)
+    with jax.named_scope("ph.diagram"):
+        rk_c, rp_c = _compact_mask(key_flat, root_mask, f)
+        order_desc = jnp.argsort(rk_c)[::-1].astype(jnp.int32)
+        root_key = rk_c[order_desc]
+        root_pix = rp_c[order_desc]
+        rvalid = root_key > key_pad(root_key.dtype)
 
-    # pixel id -> compact slot through one O(f log f) sorted table.
-    imax = jnp.int32(jnp.iinfo(jnp.int32).max)
-    pix_or_max = jnp.where(rvalid, root_pix, imax)
-    order = jnp.argsort(pix_or_max).astype(jnp.int32)
-    sorted_pix = pix_or_max[order]
-    sa, fa = _slot_lookup(sorted_pix, order, e_a)
-    sb, fb = _slot_lookup(sorted_pix, order, e_b)
-    e_key_c = jnp.where(fa & fb, e_key, key_pad(e_key.dtype))
+    with jax.named_scope("ph.merge"):
+        e_val = image_flat[e_pos]
+        # pixel id -> compact slot through one O(f log f) sorted table.
+        imax = jnp.int32(jnp.iinfo(jnp.int32).max)
+        pix_or_max = jnp.where(rvalid, root_pix, imax)
+        order = jnp.argsort(pix_or_max).astype(jnp.int32)
+        sorted_pix = pix_or_max[order]
+        sa, fa = _slot_lookup(sorted_pix, order, e_a)
+        sb, fb = _slot_lookup(sorted_pix, order, e_b)
+        e_key_c = jnp.where(fa & fb, e_key, key_pad(e_key.dtype))
 
-    c = jnp.sum(root_mask, dtype=jnp.int32)
-    reduce_fn = functools.partial(best_edge_reduce,
-                                  block_edges=phase_c_block,
-                                  use_pallas=use_pallas,
-                                  interpret=interpret)
-    dval_c, dpos_c, rounds = boruvka_forest(
-        root_key, e_key_c, e_val, e_pos, sa, sb,
-        n_live=jnp.minimum(c, f), reduce_fn=reduce_fn)
+        c = jnp.sum(root_mask, dtype=jnp.int32)
+        reduce_fn = functools.partial(best_edge_reduce,
+                                      block_edges=phase_c_block,
+                                      use_pallas=use_pallas,
+                                      interpret=interpret)
+        dval_c, dpos_c, rounds = boruvka_forest(
+            root_key, e_key_c, e_val, e_pos, sa, sb,
+            n_live=jnp.minimum(c, f), reduce_fn=reduce_fn)
 
-    n_cand = jnp.sum(cand_flat, dtype=jnp.int32)
-    overflow = n_cand > min(max_candidates, n)
+        n_cand = jnp.sum(cand_flat, dtype=jnp.int32)
+        overflow = n_cand > min(max_candidates, n)
     return root_key, root_pix, rvalid, dval_c, dpos_c, overflow, rounds

@@ -39,6 +39,7 @@ from repro.core.packed_keys import check_finite, key_scope, \
     resolve_merge_keys
 from repro.distributed.context import shard_map_compat
 from repro.kernels import backend
+from repro.ph import trace
 from repro.ph.config import FilterLevel, OverlapSpec, PHConfig, TileSpec
 from repro.ph.overlap import OverlapCounters, PendingResult, start_d2h
 
@@ -62,6 +63,11 @@ def threshold_dtype(image_dtype):
         else jnp.float32
 
 
+def _abstract(a) -> jax.ShapeDtypeStruct:
+    dtype = a.dtype if hasattr(a, "dtype") else np.result_type(a)
+    return jax.ShapeDtypeStruct(np.shape(a), dtype)
+
+
 class Plan:
     """One cached compiled executable plus its trace/call counters.
 
@@ -79,10 +85,16 @@ class Plan:
     both pay (and double-count) the trace; once ``traces > 0`` the
     compiled executable is reached without the lock, so steady-state
     calls run concurrently.
+
+    Tracing: every plan registers in :mod:`repro.ph.trace` under ``id``
+    and notes that id on the innermost open span of each call; the first
+    call keeps the abstract arguments (``avals``) for :meth:`stage_map`.
+    They are taken from the call's arguments, not inside the traced
+    function, where a ``shard_map`` body sees per-shard shapes.
     """
 
     __slots__ = ("fn", "key", "traces", "calls", "merge_keys", "impls",
-                 "_lock")
+                 "id", "avals", "_lock", "__weakref__")
 
     def __init__(self, fn: Callable, key: tuple, merge_keys: str = "rank",
                  impls: dict | None = None):
@@ -92,14 +104,19 @@ class Plan:
         self.calls = 0
         self.merge_keys = merge_keys
         self.impls = impls if impls is not None else {}
+        self.avals = None
         self._lock = threading.Lock()
+        self.id = trace.register_plan(self)
 
     def __call__(self, *args):
         with self._lock:
             self.calls += 1
             cold = self.traces == 0
+        trace.note(plan=self.id)
         if cold:
             with self._lock:
+                if self.avals is None:
+                    self.avals = jax.tree.map(_abstract, args)
                 with key_scope(self.merge_keys):
                     return self.fn(*args)
         with key_scope(self.merge_keys):
@@ -109,6 +126,16 @@ class Plan:
         """``fn.lower(*args)`` under the plan's key scope (dryrun path)."""
         with key_scope(self.merge_keys):
             return self.fn.lower(*args)
+
+    def stage_map(self) -> dict[str, str]:
+        """``{HLO instruction name: ph.* stage}`` of this plan's compiled
+        program (:func:`repro.ph.trace.stage_map`), for reading a device
+        trace by stage.  Compiles the first call's arguments again (a
+        persistent-cache hit where the cache is on); empty before the
+        first call."""
+        if self.avals is None:
+            return {}
+        return trace.stage_map(self.lower(*self.avals).compile().as_text())
 
 
 @dataclasses.dataclass(frozen=True)
@@ -432,7 +459,7 @@ class PHEngine:
             kw = self._ph_kwargs(mf, mc, mk, eff)
             dp = ctx.dp_axes
             out_specs = Diagram(P(dp, None), P(dp, None), P(dp, None),
-                                P(dp, None), P(dp), P(dp), P(dp))
+                                P(dp, None), P(dp), P(dp), P(dp), P(dp))
 
             def compute(images, tvals):
                 plan.traces += 1
@@ -619,7 +646,11 @@ class PHEngine:
 
         ``memo_key`` makes grown capacities sticky: a later call for the
         same (kind, shape, dtype) starts at the largest capacity already
-        discovered instead of re-walking the doubling chain."""
+        discovered instead of re-walking the doubling chain.
+
+        Spans (:mod:`repro.ph.trace`): ``ph.dispatch`` around each
+        dispatch, ``ph.wait`` around each overflow check, ``ph.regrow``
+        around each replay."""
         cfg = self.config
         mf0, mc0 = self.initial_capacities(n)
         if cfg.auto_regrow and memo_key is not None:
@@ -628,13 +659,23 @@ class PHEngine:
             if got:
                 mf0 = max(mf0, min(got[0], n))
                 mc0 = max(mc0, min(got[1], n))
-        out0 = dispatch(mf0, mc0)
-        if stream:
-            start_d2h(out0, self.overlap_counters)
+
+        def launch(mf, mc):
+            with trace.span("ph.dispatch", max_candidates=mc):
+                out = dispatch(mf, mc)
+                if stream:
+                    start_d2h(out, self.overlap_counters)
+                return out
+
+        def check(out):
+            with trace.span("ph.wait"):
+                return overflowed(out)
+
+        out0 = launch(mf0, mc0)
 
         def finish(out=out0, mf=mf0, mc=mc0):
             attempts = 0
-            over = overflowed(out)  # drains the in-flight copy if streamed
+            over = check(out)  # drains the in-flight copy if streamed
             while over and cfg.auto_regrow and attempts < cfg.max_regrows:
                 nmf, nmc = self.grow_capacities(mf, mc, n)
                 if (nmf, nmc) == (mf, mc):
@@ -644,8 +685,9 @@ class PHEngine:
                                             "to": (nmf, nmc)})
                 mf, mc = nmf, nmc
                 attempts += 1
-                out = dispatch(mf, mc)
-                over = overflowed(out)
+                with trace.span("ph.regrow", max_candidates=mc):
+                    out = launch(mf, mc)
+                    over = check(out)
             if attempts and memo_key is not None:
                 with self._lock:
                     got = self._grown.get(memo_key)
@@ -703,15 +745,17 @@ class PHEngine:
         if self.config.filter_level is FilterLevel.VANILLA:
             return None
         from repro.data import astro
-        host = np.asarray(image)
-        if self.config.filtration == "sublevel":
-            # The astro statistic keeps the brightest pixels of a
-            # superlevel analysis; its exact sublevel mirror is the
-            # negation on both sides (keep <= -t of -image == keep >= t).
-            t, _ = astro.filter_threshold(-host, self.config.filter_level)
-            return None if t is None else -t
-        t, _ = astro.filter_threshold(host, self.config.filter_level)
-        return t
+        with trace.span("ph.threshold"):
+            host = np.asarray(image)
+            if self.config.filtration == "sublevel":
+                # The astro statistic keeps the brightest pixels of a
+                # superlevel analysis; its exact sublevel mirror is the
+                # negation on both sides (keep <= -t of -image == keep >= t).
+                t, _ = astro.filter_threshold(-host,
+                                              self.config.filter_level)
+                return None if t is None else -t
+            t, _ = astro.filter_threshold(host, self.config.filter_level)
+            return t
 
     def auto_threshold(self, image) -> float | None:
         """The Variant-2 threshold ``config.filter_level`` implies for
@@ -823,27 +867,45 @@ class PHEngine:
         ``truncate_value`` overrides the config's ``filter_level`` (pass an
         explicit Variant-2 threshold); with the default ``None`` the
         threshold is derived from ``config.filter_level``.
+
+        Records a ``ph.run`` span (:mod:`repro.ph.trace`) with the plan id
+        that finished, ``pixels``, the final ``max_candidates`` tier, the
+        regrow ``attempts`` and the ``candidates`` the merge swept (read
+        with the overflow flag, in the call's one blocking readback).
         """
-        x = self.cast_input(image)
-        if x.ndim != 2:
-            raise ValueError(f"expected 2D image, got shape {x.shape}")
-        if truncate_value is None:
-            truncate_value = self._auto_threshold(image)
-        n = x.size
-        truncated = truncate_value is not None
-        shape, dtype = x.shape, x.dtype
+        with trace.span("ph.run") as rec:
+            with trace.span("ph.cast"):
+                x = self.cast_input(image)
+            if x.ndim != 2:
+                raise ValueError(f"expected 2D image, got shape {x.shape}")
+            if truncate_value is None:
+                truncate_value = self._auto_threshold(image)
+            n = x.size
+            truncated = truncate_value is not None
+            shape, dtype = x.shape, x.dtype
+            ran = {}
 
-        def dispatch(mf, mc):
-            plan = self._local_plan("single", shape, dtype, mf, mc,
-                                    truncated)
-            if truncated:
-                return plan(x, jnp.asarray(truncate_value,
-                                           threshold_dtype(x.dtype)))
-            return plan(x)
+            def dispatch(mf, mc):
+                plan = self._local_plan("single", shape, dtype, mf, mc,
+                                        truncated)
+                ran["plan"] = plan.id
+                if truncated:
+                    return plan(x, jnp.asarray(truncate_value,
+                                               threshold_dtype(x.dtype)))
+                return plan(x)
 
-        diag, stats = self.run_with_regrow(
-            dispatch, lambda d: bool(d.overflow), n, "single",
-            memo_key=("single", shape, str(dtype)))
+            def overflowed(d):
+                over, ran["candidates"] = jax.device_get(
+                    (d.overflow, d.n_candidates))
+                return bool(over)
+
+            diag, stats = self.run_with_regrow(
+                dispatch, overflowed, n, "single",
+                memo_key=("single", shape, str(dtype)))
+            rec.attrs.update(plan=ran["plan"], pixels=n,
+                             max_candidates=stats.final_max_candidates,
+                             attempts=stats.attempts,
+                             candidates=int(ran["candidates"]))
         return PHResult(diag, self.config.replace(
             max_features=stats.final_max_features,
             max_candidates=stats.final_max_candidates), stats,
@@ -1369,19 +1431,21 @@ class PHEngine:
 
         attempts = 0
         while True:
-            if staged is not None:
-                plan = self.tiled_stacks_plan(tuple(shape), dtype, grid,
-                                              mf, tf, tk, truncated, ctx)
-                out = plan(staged.pvals, staged.pgidx, tvj) if truncated \
-                    else plan(staged.pvals, staged.pgidx)
-            else:
-                plan = self.tiled_plan(shape, dtype, grid, mf, tf, tk,
-                                       truncated, ctx)
-                out = plan(x, tvj) if truncated else plan(x)
-            if self._stream_results():
-                start_d2h(out, self.overlap_counters)
-            tile_of = bool(out.tile_overflow)
-            merge_of = bool(out.merge_overflow)
+            with trace.span("ph.dispatch", max_candidates=tk):
+                if staged is not None:
+                    plan = self.tiled_stacks_plan(tuple(shape), dtype, grid,
+                                                  mf, tf, tk, truncated, ctx)
+                    out = plan(staged.pvals, staged.pgidx, tvj) \
+                        if truncated else plan(staged.pvals, staged.pgidx)
+                else:
+                    plan = self.tiled_plan(shape, dtype, grid, mf, tf, tk,
+                                           truncated, ctx)
+                    out = plan(x, tvj) if truncated else plan(x)
+                if self._stream_results():
+                    start_d2h(out, self.overlap_counters)
+            with trace.span("ph.wait"):
+                tile_of = bool(out.tile_overflow)
+                merge_of = bool(out.merge_overflow)
             if not (tile_of or merge_of) or not cfg.auto_regrow \
                     or attempts >= cfg.max_regrows:
                 break

@@ -44,6 +44,7 @@ from pathlib import Path
 
 import numpy as np
 
+from repro.ph import trace
 from repro.pipeline.scheduler import make_bucketed_schedule, normalize_images
 
 
@@ -87,9 +88,24 @@ def run_pipeline(pool, images, *, strategy: str = "part_LPT",
                  work_log: str | Path | None = None,
                  failure_injector=None, max_retries: int = 3,
                  verbose: bool = False) -> PipelineResult:
+    """Run ``images`` through ``pool`` in scheduled rounds.
+
+    The job is one ``ph.job`` span (:mod:`repro.ph.trace`): its rounds'
+    ``ph.load`` and ``ph.stage`` (the executor's), ``ph.dispatch`` (the
+    engine's regrow driver) and ``ph.harvest`` spans are its children,
+    on the loader and harvest threads too."""
+    with trace.span("ph.job") as job:
+        return _run(pool, images, job, strategy=strategy,
+                    work_log=work_log, failure_injector=failure_injector,
+                    max_retries=max_retries, verbose=verbose)
+
+
+def _run(pool, images, job, *, strategy, work_log, failure_injector,
+         max_retries, verbose) -> PipelineResult:
     t0 = time.time()
     metas = normalize_images(images,
                              default_size=getattr(pool, "image_size", 512))
+    job.attrs["images"] = len(metas)
     log_path = Path(work_log) if work_log else None
     done: dict[int, dict] = {}
 
@@ -133,7 +149,12 @@ def run_pipeline(pool, images, *, strategy: str = "part_LPT",
         # Runs on the harvest thread: blocking readbacks are free here.
         if counters is not None:
             counters.bump("harvest_syncs")
-        return pending_round.resolve()
+        with trace.adopt(job), trace.span("ph.harvest"):
+            return pending_round.resolve()
+
+    def load_in_job(rnd):
+        with trace.adopt(job):
+            return pool.load_round(rnd)
 
     while pending and attempt <= max_retries:
         attempt += 1
@@ -167,7 +188,7 @@ def run_pipeline(pool, images, *, strategy: str = "part_LPT",
             nonlocal next_load
             while (loader is not None and len(staged_q) < prefetch
                    and next_load < len(round_list)):
-                staged_q.append(loader.submit(pool.load_round,
+                staged_q.append(loader.submit(load_in_job,
                                               round_list[next_load]))
                 next_load += 1
 
@@ -232,6 +253,7 @@ def run_pipeline(pool, images, *, strategy: str = "part_LPT",
                 loader.shutdown(wait=True)
         pending = [mm for mm in metas if mm.image_id not in done]
 
+    job.attrs.update(rounds=rounds, failures=failures)
     if pending:
         raise RuntimeError(f"pipeline could not finish {len(pending)} images "
                            f"after {max_retries} retries") from last_error

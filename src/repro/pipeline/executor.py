@@ -44,6 +44,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core import Diagram
 from repro.data import astro
 from repro.ph.config import FilterLevel
+from repro.ph import trace
 from repro.ph.engine import PHEngine, threshold_dtype
 from repro.ph.overlap import PendingResult
 from repro.pipeline.padding import pad_fill_value, pad_fixup, unpad_diagram
@@ -160,11 +161,12 @@ class ShardedPHExecutor:
     def load_round(self, rnd: BucketRound) -> StagedRound:
         """Stage one scheduled round on device (thread-safe: the driver
         calls this on a background loader thread for round r+1 while round
-        r computes)."""
-        if rnd.kind == "tiled":
-            assert len(rnd.entries) == 1
-            return self.load_self_tiled(rnd, rnd.entries[0][1])
-        return self._stage_round(self._build_host_round(rnd))
+        r computes).  Recorded as a ``ph.load`` span."""
+        with trace.span("ph.load", kind=rnd.kind, images=len(rnd.entries)):
+            if rnd.kind == "tiled":
+                assert len(rnd.entries) == 1
+                return self.load_self_tiled(rnd, rnd.entries[0][1])
+            return self._stage_round(self._build_host_round(rnd))
 
     def _build_host_round(self, rnd: BucketRound) -> StagedRound:
         """Host half of staging: generate, cast, and pad one round into a
@@ -216,9 +218,10 @@ class ShardedPHExecutor:
         go up in one fused ``device_put`` (a single transfer per round,
         not a second tiny put for the scalars — the bench counts
         ``h2d_transfers`` per round to hold this at one)."""
-        staged.batch, staged.tvals = jax.device_put(
-            (staged.host_batch, staged.host_tvals),
-            (self._spec, self._tspec))
+        with trace.span("ph.stage"):
+            staged.batch, staged.tvals = jax.device_put(
+                (staged.host_batch, staged.host_tvals),
+                (self._spec, self._tspec))
         self.engine.overlap_counters.bump("h2d_transfers")
         return staged
 
@@ -231,7 +234,8 @@ class ShardedPHExecutor:
         h, _ = _require_square(meta.shape)
         provider = astro.AstroImage(meta.image_id, h)
         t = self.engine.provider_threshold(provider)
-        tiles = self.engine.stage_tiles(provider, ctx=self.ctx)
+        with trace.span("ph.stage"):
+            tiles = self.engine.stage_tiles(provider, ctx=self.ctx)
         return StagedRound(rnd, tiles=tiles, threshold=t)
 
     def load_self(self, image_ids) -> tuple[np.ndarray, np.ndarray, dict]:
@@ -260,9 +264,11 @@ class ShardedPHExecutor:
         Synchronous: dispatch *and* the blocking result readback happen
         on the calling thread (one dispatch-path sync — counted).  The
         overlapped driver calls :meth:`begin_staged` instead and resolves
-        on its harvest thread."""
+        on its harvest thread.  The resolution is a ``ph.harvest`` span."""
         self.engine.overlap_counters.bump("dispatch_syncs")
-        return self.begin_staged(staged).resolve()
+        pending = self.begin_staged(staged)
+        with trace.span("ph.harvest"):
+            return pending.resolve()
 
     def begin_staged(self, staged: StagedRound) -> PendingResult:
         """Dispatch one staged round without blocking for its results.
@@ -417,7 +423,7 @@ class ShardedPHExecutor:
                                                  d.death.dtype)]),
                 np.concatenate([d.p_birth, np.full(extra, -1, np.int32)]),
                 np.concatenate([d.p_death, np.full(extra, -1, np.int32)]),
-                d.count, d.n_unmerged, d.overflow)
+                d.count, d.n_unmerged, d.overflow, d.n_candidates)
 
         return jax.tree.map(lambda *xs: np.stack(xs), *map(padded, diags))
 

@@ -143,4 +143,4 @@ def unpad_diagram(d: Diagram, fixup, bucket: tuple[int, int]) -> Diagram:
         death[0] = env
         p_death[0] = eni
     return Diagram(d.birth, death, p_birth, p_death,
-                   d.count, d.n_unmerged, d.overflow)
+                   d.count, d.n_unmerged, d.overflow, d.n_candidates)

@@ -257,4 +257,5 @@ def test_cross_path_phase_c_matrix(path, phase_c_impl):
         got = jax.tree.map(lambda x: x[0], res)
     else:   # tiled
         got = engine.run_tiled(_MATRIX_IMG).diagram
-    _assert_fields_equal(got, want, f"{path}/{phase_c_impl}")
+    _assert_fields_equal(got, want, f"{path}/{phase_c_impl}",
+                         tiled=path == "tiled")

@@ -210,9 +210,14 @@ def _reference_diagram():
                        phase_a_impl="pooled")
 
 
-def _assert_fields_equal(got, want, msg):
+def _assert_fields_equal(got, want, msg, tiled=False):
+    """Every field but ``overflow``; a ``tiled`` diagram's
+    ``n_candidates`` counts the seam merge's pre-label candidates, never
+    fewer than the whole image's."""
+    if tiled:
+        assert int(got.n_candidates) >= int(want.n_candidates), msg
     for f in want._fields:
-        if f == "overflow":
+        if f == "overflow" or (tiled and f == "n_candidates"):
             continue
         np.testing.assert_array_equal(np.asarray(getattr(got, f)),
                                       np.asarray(getattr(want, f)),
@@ -252,4 +257,5 @@ def test_cross_path_matrix(path, phase_a_impl, merge_keys):
     else:   # tiled
         got = engine.run_tiled(_MATRIX_IMG).diagram
     _assert_fields_equal(got, want,
-                         f"{path}/{phase_a_impl}/{merge_keys}")
+                         f"{path}/{phase_a_impl}/{merge_keys}",
+                         tiled=path == "tiled")

@@ -32,8 +32,12 @@ def assert_tiled_equal(img: np.ndarray, grid, tv=None):
                            tile_max_candidates=h * w)
     assert isinstance(td, TiledDiagram)
     assert not bool(td.tile_overflow) and not bool(td.merge_overflow)
+    # The seam merge sweeps every tile's pre-label candidates: a superset
+    # of the whole image's.
+    assert int(td.diagram.n_candidates) == int(np.sum(td.n_tile_cands))
+    assert int(td.diagram.n_candidates) >= int(whole.n_candidates)
     for field in whole._fields:
-        if field == "overflow":
+        if field in ("overflow", "n_candidates"):
             continue
         np.testing.assert_array_equal(
             np.asarray(getattr(whole, field)),
@@ -88,8 +92,9 @@ def test_tiled_matches_fused_kernel_whole_image():
     td = tiled_pixhomology(jnp.asarray(img), grid=(3, 3), max_features=144,
                            tile_max_features=144, tile_max_candidates=144)
     assert isinstance(td, TiledDiagram)
+    assert int(td.diagram.n_candidates) >= int(whole.n_candidates)
     for field in whole._fields:
-        if field == "overflow":
+        if field in ("overflow", "n_candidates"):
             continue
         np.testing.assert_array_equal(
             np.asarray(getattr(whole, field)),

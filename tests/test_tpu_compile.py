@@ -84,3 +84,61 @@ def test_distance_runs_its_named_xla_path_on_v5e(one_chip, monkeypatch):
     prof = jax.ShapeDtypeStruct((b, f), jnp.float32, sharding=one_chip)
     hlo = _compiled_hlo(dist_ref.distance_matrix, tbl, tbl, prof)
     assert "tpu_custom_call" not in hlo
+
+
+def _bench(name):
+    """A module of the benchmark (``bench/`` at the repo root)."""
+    import importlib
+    import sys
+    from pathlib import Path
+    root = str(Path(__file__).resolve().parents[1])
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    return importlib.import_module(name)
+
+
+def test_phase_a_custom_call_keeps_its_benchmark_name(one_chip):
+    # The benchmark finds the kernel in a device trace by the compiled
+    # instruction name; ``pallas_call(name="phase_a")`` pins it.
+    from repro.ph import trace
+    x = jax.ShapeDtypeStruct((4096, 4096), jnp.float32, sharding=one_chip)
+    with pk.key_scope("packed"):
+        hlo = _compiled_hlo(lambda im: pha_kernel.phase_a(im), x)
+    calls = [line for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1
+    names = _bench("bench.trace").short_name(calls[0].strip())
+    assert _bench("bench.kernels.phase_a").is_call(names)
+    assert set(trace.stage_map(hlo).values()) == {"ph.phase_a", "ph.snap"}
+
+
+def test_whole_frame_program_ops_map_to_stages(one_chip, monkeypatch):
+    """The 4096² whole-frame plan the benchmark runs: every ``while`` and
+    every fusion with an ``op_name`` lies in a ``ph.*`` stage (only the
+    compiler's own relayout fusions carry none)."""
+    import re
+    from repro.ph import FilterLevel, PHConfig, PHEngine, trace
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+    cap = 262144
+    eng = PHEngine(PHConfig(filter_level=FilterLevel.STD, max_features=cap,
+                            max_candidates=cap))
+    plan = eng._local_plan("single", (4096, 4096), jnp.dtype(jnp.float32),
+                           cap, cap, True)
+    x = jax.ShapeDtypeStruct((4096, 4096), jnp.float32, sharding=one_chip)
+    tv = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
+    hlo = plan.lower(x, tv).compile().as_text()
+    smap = trace.stage_map(hlo)
+    ops = re.compile(r"^\s*(?:ROOT\s+)?%([\w.\-]+) = .*? (while|fusion)\(")
+    seen = {"while": 0, "fusion": 0}
+    for line in hlo.splitlines():
+        m = ops.match(line)
+        if m is None:
+            continue
+        name, op = m.groups()
+        seen[op] += 1
+        if op == "while" or "op_name=" in line:
+            assert name in smap, line[:200]
+    assert seen["while"] >= 3 and seen["fusion"] > 0
+    assert set(smap.values()) == set(trace.STAGES) - {"ph.seam"}
+    assert smap[next(n for n in smap if n.startswith("phase_a"))] == \
+        "ph.phase_a"
