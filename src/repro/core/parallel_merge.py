@@ -29,9 +29,9 @@ whole-image path); ``repro.core.tiling`` instantiates it with vertices =
 per-tile basin roots and edges = per-tile + boundary-seam edge lists (the
 tiled path's global merge), so both paths share one bit-tested reduction.
 
-Depth: the scan is O(K) sequential steps with O(1) work; Boruvka is
-O(log C) rounds of O(E) parallel work — on a systolic/vector machine depth
-is what matters (src/repro/ph/DESIGN.md §Perf PH-2).
+Depth: the scan is O(K) sequential steps (K live candidates) with O(1)
+work; Boruvka is O(log C) rounds of O(E) parallel work — on a
+systolic/vector machine depth is what matters (src/repro/ph/DESIGN.md §Perf PH-2).
 """
 from __future__ import annotations
 

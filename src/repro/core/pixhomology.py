@@ -56,9 +56,10 @@ fidelity; the axis saddle test can miss merge points on adversarial
 images — documented in DESIGN.md §6).
 
 All shapes are static (jit/vmap/shard_map friendly): diagrams are padded to
-``max_features`` rows and candidate processing to ``max_candidates`` steps,
-with explicit overflow flags so a driver can detect undersized capacities and
-re-dispatch (fault-tolerance hook used by the pipeline).
+``max_features`` rows and candidate selection to ``max_candidates`` entries
+(the merge sweep runs only the live ones), with explicit overflow flags so
+a driver can detect undersized capacities and re-dispatch (fault-tolerance
+hook used by the pipeline).
 """
 from __future__ import annotations
 
@@ -380,6 +381,88 @@ def _find_vec(parent: jnp.ndarray, start: jnp.ndarray) -> jnp.ndarray:
     return p
 
 
+def _merge_step(shape, i, carry, top_pix, top_keys, image_flat, key_flat,
+                labels_flat):
+    """One elder-rule merge of the sweep: candidate ``i`` of the top-k."""
+    h, w = shape
+    n = h * w
+    pad = key_pad(key_flat.dtype)
+    parent, dval, dpos = carry
+    x, xkey = top_pix[i], top_keys[i]
+    valid = xkey > pad
+    ok, basin = higher_neighbor_basins(x, xkey, key_flat, labels_flat,
+                                       (h, w), valid)  # (8,) each
+
+    start = jnp.where(ok, basin, x)      # x is never a root: safe filler
+    roots = _find_vec(parent, start)
+    root_key = jnp.where(ok, key_flat[roots], pad)
+    elder = roots[jnp.argmax(root_key)]
+
+    # Deduplicate equal roots among the 8 slots; younger distinct roots die.
+    dup = jnp.zeros(8, bool)
+    for j in range(1, 8):
+        seen = (roots[:j] == roots[j]) & ok[:j]
+        dup = dup.at[j].set(jnp.any(seen))
+    die = ok & ~dup & (roots != elder)
+
+    drop = jnp.int32(n)  # scatter target for masked-out lanes
+    parent = parent.at[jnp.where(ok, roots, drop)].set(elder, mode="drop")
+    parent = parent.at[jnp.where(ok, basin, drop)].set(elder, mode="drop")
+    dval = dval.at[jnp.where(die, roots, drop)].set(
+        image_flat[x], mode="drop")
+    dpos = dpos.at[jnp.where(die, roots, drop)].set(x, mode="drop")
+    return parent, dval, dpos
+
+
+def _sweep_loop(shape, m, arrays, in_axes=None, axis_size=None):
+    """Run :func:`_merge_step` for ``i = 0 .. m-1``; ``(dval, dpos)``.
+
+    ``in_axes`` (one entry per array, 0 or None) vectorizes the step over
+    a batch of ``axis_size`` sweeps that share the scalar bound ``m``.
+    """
+    n = shape[0] * shape[1]
+    dtype = arrays[2].dtype  # image_flat
+    neg_inf = (-jnp.inf if jnp.issubdtype(dtype, jnp.floating)
+               else jnp.iinfo(dtype).min)
+    carry = (jnp.arange(n, dtype=jnp.int32), jnp.full(n, neg_inf, dtype),
+             jnp.full(n, -1, jnp.int32))
+    step = functools.partial(_merge_step, shape)
+    if in_axes is not None:
+        step = jax.vmap(step, in_axes=(None, 0, *in_axes))
+        carry = tuple(jnp.broadcast_to(c, (axis_size, n)) for c in carry)
+
+    def body(state):
+        i, carry = state
+        return i + jnp.int32(1), step(i, carry, *arrays)
+
+    _, (_, dval, dpos) = jax.lax.while_loop(
+        lambda state: state[0] < m, body, (jnp.int32(0), carry))
+    return dval, dpos
+
+
+def _merge_sweep(shape, m, *arrays):
+    """The elder-rule sweep over the first ``m`` entries of the top-k.
+
+    Under ``vmap`` a while loop with a per-image bound would select every
+    carried n-length array on every step; the batching rule instead runs
+    one loop to the batch's largest bound with the step vectorized.  An
+    image whose own bound is smaller sweeps pad keys there, which its
+    ``valid`` mask turns into no-ops.
+    """
+    @jax.custom_batching.custom_vmap
+    def sweep(m, *arrays):
+        return _sweep_loop(shape, m, arrays)
+
+    @sweep.def_vmap
+    def sweep_batched(axis_size, in_batched, m, *arrays):
+        m_all = jnp.max(m) if in_batched[0] else m
+        in_axes = tuple(0 if b else None for b in in_batched[1:])
+        return _sweep_loop(shape, m_all, arrays, in_axes, axis_size), \
+            (True, True)
+
+    return sweep(m, *arrays)
+
+
 def merge_components(image_flat: jnp.ndarray, key_flat: jnp.ndarray,
                      labels_flat: jnp.ndarray, cand_flat: jnp.ndarray,
                      shape: tuple[int, int], max_candidates: int):
@@ -397,55 +480,26 @@ def merge_components(image_flat: jnp.ndarray, key_flat: jnp.ndarray,
     full-array ``top_k`` (its ranks already cost a full argsort, so
     there is nothing to save).
 
+    ``max_candidates`` sizes the top-k and nothing else: the sweep stops
+    after ``min(n_cand, max_candidates)`` steps, the live candidates,
+    which the top-k places first (every later entry carries the pad key
+    and would merge nothing).
+
     Returns (death_val, death_pos, overflow): per-root death records.
     """
     h, w = shape
     n = h * w
     k = min(max_candidates, n)
-    pad = key_pad(key_flat.dtype)
 
     with jax.named_scope("ph.select"):
         n_cand = jnp.sum(cand_flat, dtype=jnp.int32)
         top_keys, top_pix = masked_top_k(key_flat, cand_flat, k)  # desc.
         overflow = n_cand > k
 
-    neg_inf = (-jnp.inf if jnp.issubdtype(image_flat.dtype, jnp.floating)
-               else jnp.iinfo(image_flat.dtype).min)
-
-    def step(carry, xs):
-        parent, dval, dpos = carry
-        x, xkey = xs
-        valid = xkey > pad
-        ok, basin = higher_neighbor_basins(x, xkey, key_flat, labels_flat,
-                                           (h, w), valid)  # (8,) each
-
-        start = jnp.where(ok, basin, x)      # x is never a root: safe filler
-        roots = _find_vec(parent, start)
-        root_key = jnp.where(ok, key_flat[roots], pad)
-        elder = roots[jnp.argmax(root_key)]
-
-        # Deduplicate equal roots among the 8 slots; younger distinct roots die.
-        dup = jnp.zeros(8, bool)
-        for j in range(1, 8):
-            seen = (roots[:j] == roots[j]) & ok[:j]
-            dup = dup.at[j].set(jnp.any(seen))
-        die = ok & ~dup & (roots != elder)
-
-        drop = jnp.int32(n)  # scatter target for masked-out lanes
-        parent = parent.at[jnp.where(ok, roots, drop)].set(elder, mode="drop")
-        parent = parent.at[jnp.where(ok, basin, drop)].set(elder, mode="drop")
-        dval = dval.at[jnp.where(die, roots, drop)].set(
-            image_flat[x], mode="drop")
-        dpos = dpos.at[jnp.where(die, roots, drop)].set(x, mode="drop")
-        return (parent, dval, dpos), None
-
     with jax.named_scope("ph.merge"):
-        parent0 = jnp.arange(n, dtype=jnp.int32)
-        dval0 = jnp.full(n, neg_inf, image_flat.dtype)
-        dpos0 = jnp.full(n, -1, jnp.int32)
-        (parent, dval, dpos), _ = jax.lax.scan(
-            step, (parent0, dval0, dpos0), (top_pix, top_keys))
-    del parent
+        dval, dpos = _merge_sweep(
+            (h, w), jnp.minimum(n_cand, jnp.int32(k)), top_pix, top_keys,
+            image_flat, key_flat, labels_flat)
     return dval, dpos, overflow
 
 

@@ -4,10 +4,13 @@ The paper validates against Ripser with bottleneck distance 0 (fig 7); we
 assert *exact* equality (values AND pixel coordinates) against the oracle,
 which is stronger, plus property-based sweeps with hypothesis.
 """
+import importlib
+
 import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
+from jax.extend import core as jcore
 from _hypothesis_compat import given, settings, st
 
 from repro.core import (
@@ -210,3 +213,142 @@ def test_num_candidates_agrees_across_stage_impls():
     t = float(np.asarray(img).mean())
     assert int(num_candidates(img, truncate_value=t)) == \
         int(num_candidates(img, truncate_value=t, phase_a_impl="pooled"))
+
+
+# ---------------------------------------------------------------------------
+# Merge sweep length: the scan runs min(n_cand, max_candidates) steps
+# ---------------------------------------------------------------------------
+
+_ph_mod = importlib.import_module("repro.core.pixhomology")
+
+
+def _fixed_length_sweep(shape, m, top_pix, top_keys, image_flat, key_flat,
+                        labels_flat):
+    """Reference: the same step over every top-k entry, pad keys included."""
+    n = shape[0] * shape[1]
+    neg_inf = (-jnp.inf if jnp.issubdtype(image_flat.dtype, jnp.floating)
+               else jnp.iinfo(image_flat.dtype).min)
+    carry = (jnp.arange(n, dtype=jnp.int32),
+             jnp.full(n, neg_inf, image_flat.dtype), jnp.full(n, -1, jnp.int32))
+
+    def body(carry, i):
+        return _ph_mod._merge_step(shape, i, carry, top_pix, top_keys,
+                                   image_flat, key_flat, labels_flat), None
+
+    (_, dval, dpos), _ = jax.lax.scan(
+        body, carry, jnp.arange(top_pix.shape[0], dtype=jnp.int32))
+    return dval, dpos
+
+
+def _sweep_loops(jaxpr):
+    """(kind, length or predicate shape, outvar shapes) of every loop."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            found.append(("scan", eqn.params["length"], None))
+        elif eqn.primitive.name == "while":
+            found.append(("while", eqn.params["cond_jaxpr"].out_avals[0].shape,
+                          [v.aval.shape for v in eqn.outvars]))
+        for p in eqn.params.values():
+            for q in p if isinstance(p, (tuple, list)) else (p,):
+                if isinstance(q, jcore.ClosedJaxpr):
+                    found += _sweep_loops(q.jaxpr)
+                elif isinstance(q, jcore.Jaxpr):
+                    found += _sweep_loops(q)
+    return found
+
+
+def _sweep_case(case, truncated):
+    """(images, truncate values or None, max_candidates) for one case."""
+    rng = np.random.default_rng(17)
+    h, w = 9, 10
+    if case == "one_candidate":
+        # Two peaks joined through one saddle pixel.
+        imgs = np.array([[[3.0, 0.0, 2.0]]], np.float32)
+    elif case == "batch":
+        r, c = np.mgrid[:h, :w]
+        two_cones = np.maximum(-((r - 2) ** 2 + (c - 2) ** 2),
+                               -((r - 6) ** 2 + (c - 7) ** 2) - 1)
+        imgs = np.stack([np.full((h, w), 2.0, np.float32),
+                         rng.normal(size=(h, w)).astype(np.float32),
+                         two_cones.astype(np.float32),
+                         rng.normal(size=(h, w)).astype(np.float32) * 3])
+    elif case == "zero_candidates":
+        imgs = np.full((1, h, w), 5.0, np.float32)
+    else:
+        imgs = rng.normal(size=(1, h, w)).astype(np.float32)
+    tvals = (np.quantile(imgs.reshape(len(imgs), -1), 0.3, axis=1
+                         ).astype(np.float32) if truncated else None)
+    counts = [int(num_candidates(jnp.asarray(im),
+                                 truncate_value=None if tvals is None
+                                 else tvals[i]))
+              for i, im in enumerate(imgs)]
+    n = imgs[0].size
+    k = {"less": max(counts) + 3, "equal": max(counts),
+         "overflow": max(counts) // 2, "batch": int(np.median(counts))}.get(
+             case, n)
+    if case == "zero_candidates":
+        assert counts == [0]
+    if case == "one_candidate" and not truncated:
+        assert counts == [1]
+    if case == "batch":
+        assert len(set(counts)) == len(counts)   # a different count each
+        assert min(counts) < k < max(counts)     # one image overflows
+    return imgs, tvals, max(k, 1)
+
+
+@pytest.mark.parametrize("merge_keys", ["rank", "packed"])
+@pytest.mark.parametrize("truncated", [False, True],
+                         ids=["untruncated", "truncated"])
+@pytest.mark.parametrize("case", ["zero_candidates", "one_candidate", "less",
+                                  "equal", "overflow", "batch"])
+def test_merge_sweep_stops_at_live_candidates(case, truncated, merge_keys,
+                                              monkeypatch):
+    """Bounding the sweep by the live count changes no bit of the diagram.
+
+    The diagram (and overflow flag) equals the one a fixed-length sweep
+    over all ``max_candidates`` entries gives, and the oracle's when
+    nothing overflows; the program holds no scan of that length, and its
+    merge loop runs on a scalar bound, batched or not."""
+    imgs, tvals, k = _sweep_case(case, truncated)
+    kw = dict(max_features=imgs[0].size, max_candidates=k,
+              merge_keys=merge_keys)
+    args = (jnp.asarray(imgs),) if tvals is None else \
+        (jnp.asarray(imgs), jnp.asarray(tvals))
+
+    def make_run():
+        # A fresh function per sweep, so no trace cached under the other
+        # sweep is reused.
+        def run(x, t=None):
+            if case == "batch":
+                return batched_pixhomology(x, t, **kw)
+            return jax.tree.map(lambda a: a[None], pixhomology(
+                x[0], None if t is None else t[0], **kw))
+        return run
+
+    got = jax.jit(make_run())(*args)
+    loops = _sweep_loops(jax.make_jaxpr(make_run())(*args).jaxpr)
+    with monkeypatch.context() as mp:
+        mp.setattr(_ph_mod, "_merge_sweep", _fixed_length_sweep)
+        raw = _ph_mod._pixhomology.__wrapped__
+        mp.setattr(_ph_mod, "_pixhomology", lambda *a, **kws: raw(*a, **kws))
+        want = jax.jit(make_run())(*args)
+        assert ("scan", k, None) in _sweep_loops(
+            jax.make_jaxpr(make_run())(*args).jaxpr)
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    n_cand = np.asarray(got.n_candidates)
+    np.testing.assert_array_equal(np.asarray(got.overflow), n_cand > k)
+    for i, img in enumerate(imgs):
+        if tvals is None and n_cand[i] <= k:
+            one = Diagram(*(np.asarray(f)[i] for f in got))
+            np.testing.assert_array_equal(diagram_to_array(one),
+                                          persistence_oracle(img))
+
+    n = imgs[0].size
+    assert ("scan", k, None) not in loops
+    merge = [(pred, shapes) for kind, pred, shapes in loops
+             if kind == "while" and shapes[1:] == [shapes[1]] * 3
+             and shapes[1][-1:] == (n,)]
+    assert [pred for pred, _ in merge] == [()]
