@@ -93,7 +93,10 @@ def run_pipeline(pool, images, *, strategy: str = "part_LPT",
     The job is one ``ph.job`` span (:mod:`repro.ph.trace`): its rounds'
     ``ph.load`` and ``ph.stage`` (the executor's), ``ph.dispatch`` (the
     engine's regrow driver) and ``ph.harvest`` spans are its children,
-    on the loader and harvest threads too."""
+    on the loader and harvest threads too.  ``ph.load_wait`` spans the
+    dispatch loop's wait for each staged round (an inline ``ph.load``
+    with prefetch off), so it reads how far the host loader sets the
+    job's pace."""
     with trace.span("ph.job") as job:
         return _run(pool, images, job, strategy=strategy,
                     work_log=work_log, failure_injector=failure_injector,
@@ -197,11 +200,12 @@ def _run(pool, images, job, *, strategy, work_log, failure_injector,
                 # Double buffering: the loader thread stages ahead while
                 # this thread computes; with prefetch off, load inline.
                 top_up()
-                if staged_q:
-                    staged = staged_q.pop(0).result()
-                else:
-                    staged = pool.load_round(rnd)
-                    next_load += 1
+                with trace.span("ph.load_wait"):
+                    if staged_q:
+                        staged = staged_q.pop(0).result()
+                    else:
+                        staged = pool.load_round(rnd)
+                        next_load += 1
                 top_up()
                 if failure_injector:
                     failure_injector(seq)

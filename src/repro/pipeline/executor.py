@@ -303,6 +303,12 @@ class ShardedPHExecutor:
                 if staged.fixups[k] is not None:
                     d = unpad_diagram(d, staged.fixups[k], rnd.shape)
                 out[meta.image_id] = d
+            # On the open ph.harvest span: each real frame's swept
+            # candidates, in slot order, and the chips the round held.
+            slots = sorted(slot for slot, _ in rnd.entries)
+            trace.note(candidates=[int(diags.n_candidates[s])
+                                   for s in slots],
+                       chips=self.num_executors)
             return out
 
         return PendingResult(whole_finish)

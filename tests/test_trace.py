@@ -215,7 +215,7 @@ def test_distributed_job_spans_join_one_call(overlap):
     by_id = {s.span_id: s for s in mine}
     names = [s.name for s in mine]
     for name in ("ph.load", "ph.stage", "ph.dispatch", "ph.harvest",
-                 "ph.wait"):
+                 "ph.wait", "ph.load_wait"):
         assert names.count(name) == 3, (name, names)
     for s in mine:
         if s is job:
@@ -223,8 +223,20 @@ def test_distributed_job_spans_join_one_call(overlap):
         parent = by_id[s.parent_id].name
         want = {"ph.load": "ph.job", "ph.stage": "ph.load",
                 "ph.dispatch": "ph.job", "ph.harvest": "ph.job",
-                "ph.wait": "ph.harvest", "ph.threshold": "ph.load"}
+                "ph.wait": "ph.harvest", "ph.threshold": "ph.load",
+                "ph.load_wait": "ph.job"}
         assert parent == want[s.name], (s.name, parent)
+    # Each round's harvest carries its one frame's swept candidates and
+    # the round's chips.
+    harvests = [s.attrs for s in mine if s.name == "ph.harvest"]
+    assert all(set(a) == {"candidates", "chips"} and a["chips"] == 1
+               for a in harvests), harvests
+    want = []
+    for i in range(3):
+        img = astro.generate_image(i, 32)
+        want.append(eng.num_candidates(img, eng.auto_threshold(img)))
+    assert sorted(c for a in harvests for c in a["candidates"]) == \
+        sorted(want)
     # Rounds 2 and 3 load on the prefetch thread, while the main thread
     # dispatches: their load spans overlap other spans of the job.
     assert sum(s.name == "ph.threshold" for s in mine) == 3
